@@ -219,8 +219,9 @@ def kernel_sweep_case(smoke: bool = False) -> dict:
 
 
 def kernel_identity_case() -> dict:
-    """Bitwise identity of the real kernels at one small shape: the
-    fused interleave is a pure permutation of the split walk, and
+    """Bitwise identity of the real kernels at one small shape: the split
+    walk reads the K and V halves of the fused pool as two DMAs and hands
+    the flash step the same tiles the fused walk reads as one, and
     pipelining only moves *when* bytes reach VMEM — so fused == split
     and every buffer depth == the unpipelined fused walk, exactly."""
     import jax
@@ -238,9 +239,9 @@ def kernel_identity_case() -> dict:
     tables = jnp.asarray(np.random.RandomState(0).permutation(N)[
         :B * M].reshape(B, M).astype(np.int32))
     lengths = jnp.asarray([bs + 3, M * bs, 2 * bs - 1], jnp.int32)
-    split = paged_attention_split(q, kp, vp, tables, lengths,
-                                  interpret=True)
     kv = fuse_kv(kp, vp)
+    split = paged_attention_split(q, kv[:, 0], kv[:, 1], tables, lengths,
+                                  interpret=True)
     fused = paged_attention(q, kv, tables, lengths, interpret=True)
     out = {"fused_eq_split": bool(jnp.array_equal(fused, split))}
     for d in (2, 4):

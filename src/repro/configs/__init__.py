@@ -8,7 +8,7 @@ the assigned input-shape set shared by every LM arch.
 from __future__ import annotations
 
 import importlib
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 ARCH_IDS = (
     "jamba-v0.1-52b",
@@ -47,9 +47,19 @@ SHAPES = {
 LONG_CONTEXT_ARCHS = {"jamba-v0.1-52b", "rwkv6-7b", "h2o-danube-3-4b"}
 
 
-def get_config(arch_id: str):
+def get_config(arch_id: str, n_layers: int | None = None):
+    """The published config; ``n_layers=k`` keeps only its first ``k``
+    layers (per-layer mixer/ffn patterns sliced to match) — a depth cut
+    at full width, for fitting a model onto fewer chips."""
     mod = importlib.import_module(_MODULE_OF[arch_id])
-    return mod.config()
+    cfg = mod.config()
+    if n_layers is None:
+        return cfg
+    if not 1 <= n_layers <= cfg.n_layers:
+        raise ValueError(f"{arch_id} has {cfg.n_layers} layers; "
+                         f"cannot keep {n_layers}")
+    return replace(cfg, n_layers=n_layers, mixers=cfg.mixers[:n_layers],
+                   ffns=cfg.ffns[:n_layers])
 
 
 def get_smoke(arch_id: str):

@@ -225,7 +225,7 @@ written history the next chunk reads).
 (:mod:`repro.kernels.paged_attention`) is the *reader* side of the paper's
 "one translation, more reach" argument.  A translation the fence protocol
 guarantees valid is a block-table row; what that row buys per lookup is
-the kernel's business.  Fusing K and V head-interleaved into one pool
+the kernel's business.  Fusing K and V side by side into one pool
 block means each validated row now covers **one** contiguous DMA carrying
 the block's entire KV payload instead of two half-sized descriptors
 walking two pools — twice the reach per translation, half the page walks

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed._compat import pvary, shard_map
+from jax import shard_map
 
 NEG_INF = -1e30
 
@@ -64,7 +64,9 @@ def _localize(tables, offset, Nl):
 def _pvary(x, axes):
     """Mark a shard-invariant init as varying over ``axes`` (scan inside
     shard_map requires carry in/out varying-axis types to match)."""
-    return pvary(x, axes)
+    if not axes:
+        return x
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def _lse_combine(m, l, acc, axes):

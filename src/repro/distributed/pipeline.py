@@ -11,12 +11,11 @@ every stage, stage params stacked on a leading axis sharded over ``axis``.
 
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed._compat import pvary, shard_map
+from jax import shard_map
 
 
 def pipeline_apply(stage_fn, stage_params, x, *, mesh, axis: str = "pipe",
@@ -45,7 +44,7 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, axis: str = "pipe",
             nxt = jax.lax.ppermute(out, axis, fwd)
             return nxt, out
 
-        act0 = pvary(jnp.zeros_like(xs_l[0]), (axis,))
+        act0 = jax.lax.pcast(jnp.zeros_like(xs_l[0]), (axis,), to="varying")
         _, outs = jax.lax.scan(tick, act0, jnp.arange(ticks))
         # stage S−1 emits microbatch t−(S−1) at tick t
         return outs[None, n_stages - 1:]                   # (1, n_micro, …)

@@ -163,8 +163,8 @@ def decode_state_specs(cfg, mesh, *, batch_axes, seq_axes) -> dict:
     sp: dict[str, P] = {}
     sp["tables"] = P(b, None)
     sp["lengths"] = P(b)
-    # paged pools: (L, N, bs, KV*2, hd) fused / (L, N, bs, rank)
-    sp["kv"] = P(None, pool, None, None, None)
+    # paged pools: (L, N, 2, KV, bs, hd) fused / (L, N, bs, rank)
+    sp["kv"] = P(None, pool, None, None, None, None)
     sp["mla_c"] = P(None, pool, None, None)
     sp["mla_rope"] = P(None, pool, None, None)
     # recurrent states: (L, B, ...) — batch over ba, channels over model

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -126,7 +124,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),      # running denom
             pltpu.VMEM((bq, hd), jnp.float32),     # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

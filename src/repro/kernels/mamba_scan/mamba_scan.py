@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 
 def _ms_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, h0_ref, y_ref, hl_ref,
@@ -90,8 +89,8 @@ def mamba_scan_fwd(dt: jax.Array, A: jax.Array, B: jax.Array, C: jax.Array,
             jax.ShapeDtypeStruct((Bsz, DI, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, B, C, x, A, h0)
     return y, h_last

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 from repro.kernels.paged_attention.paged_attention import _table_index
 
 NEG_INF = -1e30
@@ -128,7 +127,7 @@ def mla_paged_ctx_fwd(q_lat: jax.Array, q_rope: jax.Array, c_pool: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(stack.reshape(-1), lengths, q_lat, q_rope, c_pool, rope_pool)

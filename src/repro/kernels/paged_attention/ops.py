@@ -61,13 +61,13 @@ def paged_attention(q: jax.Array, kv_pool: jax.Array, tables: jax.Array,
                     lengths: jax.Array, *, window: int | None = None,
                     buffer_depth: int = 1,
                     interpret: bool = False) -> jax.Array:
-    """Fused-KV paged decode.  q: (B, H, hd); kv_pool: (N, bs, KV*2, hd)
-    head-interleaved (K even, V odd); tables: (B, M) or (W, Bs, M);
+    """Fused-KV paged decode.  q: (B, H, hd); kv_pool: (N, 2, KV, bs, hd)
+    (K at index 0 and V at 1 of axis 1); tables: (B, M) or (W, Bs, M);
     lengths: (B,) → (B, H, hd).  ``buffer_depth >= 2`` takes the manual
     multi-depth DMA pipeline; output is bit-identical either way.
     Matches attention.paged_decode_attention_ref on the split views."""
     B, H, hd = q.shape
-    KV = kv_pool.shape[2] // 2
+    KV = kv_pool.shape[2]
     G = H // KV
     qg = q.reshape(B, KV, G, hd)
     stack, _, _, _ = shard_descriptor(tables)
@@ -91,9 +91,11 @@ def paged_attention_split(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
     Kept as the *naive* baseline for the DMA-vs-compute sweep and the
     fused-vs-split differential tests; new callers should store the pool
-    fused and use :func:`paged_attention`."""
+    fused and use :func:`paged_attention`.  k_pool/v_pool: (N, KV, bs,
+    hd), the two halves ``kv_pool[:, 0]`` / ``kv_pool[:, 1]`` of a fused
+    pool."""
     B, H, hd = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     G = H // KV
     qg = q.reshape(B, KV, G, hd)
     stack, _, _, _ = shard_descriptor(tables)
@@ -182,13 +184,18 @@ def ragged_paged_attention(q: jax.Array, kv_pool: jax.Array,
     """Ragged fused-KV attention over one packed mixed batch.
 
     q: (T, H, hd) packed queries (T a multiple of :data:`QT`); kv_pool:
-    (N, bs, KV*2, hd); tables: (B, M) or (W, Bs, M); tile_row/tile_pos:
+    (N, 2, KV, bs, hd); tables: (B, M) or (W, Bs, M); tile_row/tile_pos:
     (T // QT,); kv_lens: per-slot kv lengths → (T, H, hd).  One call
     serves every chunked-prefill AND decode row of an engine step."""
     T, H, hd = q.shape
-    KV = kv_pool.shape[2] // 2
+    KV = kv_pool.shape[2]
     G = H // KV
-    qg = q.reshape(T, KV, G, hd)
+    if T % QT:
+        raise ValueError(f"packed length {T} not a multiple of QT={QT}")
+    tiles = T // QT
+    # (T, H, hd) → one (KV, G*QT, hd) slab per query tile, row g*QT + t
+    qg = q.reshape(tiles, QT, KV, G, hd).transpose(0, 2, 3, 1, 4).reshape(
+        tiles, KV, G * QT, hd)
     stack, W, Bs, _ = shard_descriptor(tables)
     kv_lens = kv_lens.astype(jnp.int32)
     if kv_lens.shape[0] < W * Bs:
@@ -198,7 +205,8 @@ def ragged_paged_attention(q: jax.Array, kv_pool: jax.Array,
                          tile_row.astype(jnp.int32),
                          tile_pos.astype(jnp.int32), kv_lens,
                          window=window, interpret=interpret)
-    return o.reshape(T, H, hd)
+    return o.reshape(tiles, KV, G, QT, hd).transpose(0, 3, 1, 2, 4).reshape(
+        T, H, hd)
 
 
 __all__ = ["paged_attention", "paged_attention_split",

@@ -11,27 +11,32 @@ from HBM into VMEM.  Holes (non-resident / swapped blocks, table entry
 touched.  Four kernels share that walk:
 
   * ``paged_attention_fwd`` — the legacy split-KV decode kernel (separate
-    ``(N, bs, KV, hd)`` K and V pools, two DMA descriptors per logical
+    ``(N, KV, bs, hd)`` K and V pools, two DMA descriptors per logical
     block).  Kept as the *naive* baseline the microbench sweep compares
     against.
   * ``paged_attention_fused_fwd`` — the fused-KV decode kernel.  The pool
-    is head-interleaved ``(N, bs, KV*2, hd)`` with K on even and V on odd
-    head indices, so one logical block is ONE contiguous DMA — one
+    is ``(N, 2, KV, bs, hd)``: K and V of one block sit side by side on
+    an untiled axis, so one logical block is ONE contiguous DMA — one
     translation covers twice the reach, the serving analogue of the
-    large-reach TLBs in PAPERS.md.  Bit-identical to the split kernel
-    (the interleave is a pure permutation; the flash math is unchanged).
+    large-reach TLBs in PAPERS.md — and the kernel reads K and V by a
+    static index (``kv_ref[0, 0]`` / ``kv_ref[0, 1]``), never by a
+    strided slice the TPU cannot lower.  Bit-identical to the split
+    kernel: both hand the same ``(KV, bs, hd)`` tiles to one flash step.
   * ``paged_attention_fused_pipelined_fwd`` — the fused kernel with
     *manual multi-depth VMEM buffering*: the fused pool stays in
-    ``pltpu.ANY`` memory and the kernel issues its own
-    ``pltpu.make_async_copy`` per block into a revolving ``(depth, bs,
-    KV*2, hd)`` VMEM buffer, so block ``m + depth``'s copy overlaps block
+    ``pl.ANY`` memory and the kernel issues its own
+    ``pltpu.make_async_copy`` per block into a revolving ``(depth, 2, KV,
+    bs, hd)`` VMEM buffer, so block ``m + depth``'s copy overlaps block
     ``m``'s flash step.  ``buffer_depth`` (2/4) and the pool block size
     are the autotune knobs (see ``autotune.py``).
   * ``ragged_fused_fwd`` — ragged batching over the fused pool: mixed
-    chunked-prefill rows and decode rows are packed into one ``(T, KV,
-    G, hd)`` query array (tiles of ``QT`` query rows, tiles never span
-    sequences) and served by ONE kernel call per step.  The descriptor —
-    derived from scalar-prefetched ``cu_q_lens`` / ``cu_kv_lens`` by
+    chunked-prefill rows and decode rows are packed into query tiles of
+    ``QT`` tokens (tiles never span sequences) and served by ONE kernel
+    call per step.  Each tile reaches the kernel as a ``(KV, G*QT, hd)``
+    slab (row ``g * QT + t`` is query head ``g`` of token ``t``), so
+    every score and value product is the same head-batched 3-D matmul
+    as the decode kernel's.  The descriptor — derived from
+    scalar-prefetched ``cu_q_lens`` / ``cu_kv_lens`` by
     ``ops.build_ragged_descriptor`` — maps each query tile to its batch
     slot and global position; causality, sequence length, sliding
     window and holes are all masked per (query, key) element.
@@ -65,13 +70,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 #: query-tile height of the ragged kernel — packed rows are padded so a
 #: tile never spans two sequences
 QT = 8
+
+#: the f32 score and value matmuls run at full f32 precision: at the
+#: default, Mosaic rounds q and the probabilities to bf16 for one MXU
+#: pass, a relative error of about 2e-3 per layer on a TPU v5e
+_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
 
 def _table_index(b, m, *, W: int, Bs: int, M: int):
@@ -80,6 +93,76 @@ def _table_index(b, m, *, W: int, Bs: int, M: int):
     if W == 1:
         return b * M + m
     return (b % W) * (Bs * M) + (b // W) * M + m
+
+
+def _check_fused(kv_pool: jax.Array, KV: int, hd: int) -> int:
+    """Validate a ``(N, 2, KV, bs, hd)`` fused pool; return its ``bs``."""
+    if (kv_pool.ndim != 5 or kv_pool.shape[1] != 2
+            or kv_pool.shape[2] != KV or kv_pool.shape[4] != hd):
+        raise ValueError(f"fused pool must be (N, 2, {KV}, bs, {hd}), "
+                         f"got {kv_pool.shape}")
+    return kv_pool.shape[3]
+
+
+def _init_state(m_sc, l_sc, acc_sc):
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+
+
+def _finalize(o_ref, l_sc, acc_sc):
+    out = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _online_softmax(s, v, m_sc, l_sc, acc_sc):
+    """Fold one masked ``(KV, R, bs)`` score block and its ``(KV, bs,
+    hd)`` values into the running (m, l, acc) state."""
+    m_prev = m_sc[...]                                # (KV, R, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)                            # (KV, R, bs)
+    scale = jnp.exp(m_prev - m_new)
+    l_sc[...] = l_sc[...] * scale + p.sum(axis=-1, keepdims=True)
+    acc_sc[...] = acc_sc[...] * scale + jnp.einsum(
+        "krs,ksd->krd", p, v, precision=_PRECISION,
+        preferred_element_type=jnp.float32)
+    m_sc[...] = m_new
+
+
+def _scores(q, k):
+    """Head-batched ``(KV, R, hd) x (KV, bs, hd) -> (KV, R, bs)``."""
+    hd = q.shape[-1]
+    return jnp.einsum("krd,ksd->krs", q, k, precision=_PRECISION,
+                      preferred_element_type=jnp.float32) * (hd ** -0.5)
+
+
+def _flash_block_step(q, k, v, blk_start, length, window,
+                      m_sc, l_sc, acc_sc):
+    """One decode online-softmax step over a ``(KV, bs, hd)`` block.
+
+    Shared verbatim by the split, fused and pipelined decode kernels —
+    same tiles, same float ops in the same order, which is what makes
+    the fused and pipelined paths *bit-identical* to the naive baseline.
+    """
+    s = _scores(q, k)                                 # (KV, G, bs)
+    pos = blk_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    mask = pos < length
+    if window is not None:
+        mask = jnp.logical_and(mask, pos > length - 1 - window)
+    _online_softmax(jnp.where(mask, s, NEG_INF), v, m_sc, l_sc, acc_sc)
+
+
+def _block_visible(blk_start, length, bs, window):
+    visible = blk_start < length
+    if window is not None:
+        visible = jnp.logical_and(visible, blk_start + bs > length - window)
+    return visible
+
+
+def _decode_scratch(KV, G, hd):
+    return [pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, hd), jnp.float32)]
 
 
 # ---------------------------------------------------------------------------
@@ -92,72 +175,38 @@ def _pa_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
                W: int, Bs: int, M: int):
     b = pl.program_id(0)
     mi = pl.program_id(1)
-    nm = pl.num_programs(1)
     length = lengths_ref[b]
 
     @pl.when(mi == 0)
     def _init():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
+        _init_state(m_sc, l_sc, acc_sc)
 
     blk_start = mi * bs
     resident = tables_ref[_table_index(b, mi, W=W, Bs=Bs, M=M)] >= 0
-    visible = blk_start < length
-    if window is not None:
-        visible = jnp.logical_and(visible, blk_start + bs > length - window)
 
-    @pl.when(jnp.logical_and(resident, visible))
+    @pl.when(jnp.logical_and(resident,
+                             _block_visible(blk_start, length, bs, window)))
     def _step():
         q = q_ref[0].astype(jnp.float32)              # (KV, G, hd)
-        k = k_ref[0].astype(jnp.float32)              # (bs, KV, hd)
-        v = v_ref[0].astype(jnp.float32)              # (bs, KV, hd)
+        k = k_ref[0].astype(jnp.float32)              # (KV, bs, hd)
+        v = v_ref[0].astype(jnp.float32)              # (KV, bs, hd)
         _flash_block_step(q, k, v, blk_start, length, window,
                           m_sc, l_sc, acc_sc)
 
-    @pl.when(mi == nm - 1)
-    def _finalize():
-        out = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
-        o_ref[0] = out.astype(o_ref.dtype)
-
-
-def _flash_block_step(q, k, v, blk_start, length, window,
-                      m_sc, l_sc, acc_sc):
-    """One online-softmax step over a (bs, KV, hd) key/value block.
-
-    Shared verbatim by the split, fused and pipelined decode kernels —
-    same float ops in the same order, which is what makes the fused and
-    pipelined paths *bit-identical* to the naive baseline.
-    """
-    hd = q.shape[-1]
-    s = jnp.einsum("kgd,skd->kgs", q, k,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
-    pos = blk_start + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 2)                        # (KV, G, bs)
-    mask = pos < length
-    if window is not None:
-        mask = jnp.logical_and(mask, pos > length - 1 - window)
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_sc[...]                                # (KV, G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                            # (KV, G, bs)
-    scale = jnp.exp(m_prev - m_new)
-    l_sc[...] = l_sc[...] * scale + p.sum(axis=-1, keepdims=True)
-    acc_sc[...] = acc_sc[...] * scale + jnp.einsum(
-        "kgs,skd->kgd", p, v, preferred_element_type=jnp.float32)
-    m_sc[...] = m_new
+    @pl.when(mi == pl.num_programs(1) - 1)
+    def _done():
+        _finalize(o_ref, l_sc, acc_sc)
 
 
 def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         shard_tables: jax.Array, lengths: jax.Array, *,
                         window: int | None = None,
                         interpret: bool = False) -> jax.Array:
-    """q: (B, KV, G, hd); pools: (N, bs, KV, hd);
-    shard_tables: (W, Bs, M) int32 interleaved shard stack (W*Bs >= B);
-    lengths: (B,) int32 → (B, KV, G, hd)."""
+    """q: (B, KV, G, hd); pools: (N, KV, bs, hd) — the K and V halves of
+    a fused pool; shard_tables: (W, Bs, M) int32 interleaved shard stack
+    (W*Bs >= B); lengths: (B,) int32 → (B, KV, G, hd)."""
     B, KV, G, hd = q.shape
-    N, bs, _, _ = k_pool.shape
+    N, _, bs, _ = k_pool.shape
     W, Bs, M = shard_tables.shape
     if W * Bs < B:
         raise ValueError(f"shard stack covers {W * Bs} slots < batch {B}")
@@ -176,15 +225,11 @@ def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid=(B, M),
         in_specs=[
             pl.BlockSpec((1, KV, G, hd), q_map),
-            pl.BlockSpec((1, bs, KV, hd), kv_map),
-            pl.BlockSpec((1, bs, KV, hd), kv_map),
+            pl.BlockSpec((1, KV, bs, hd), kv_map),
+            pl.BlockSpec((1, KV, bs, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, KV, G, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, hd), jnp.float32),
-        ],
+        scratch_shapes=_decode_scratch(KV, G, hd),
     )
     kern = functools.partial(_pa_kernel, bs=bs, window=window,
                              W=W, Bs=Bs, M=M)
@@ -192,14 +237,13 @@ def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(shard_tables.reshape(-1), lengths, q, k_pool, v_pool)
 
 
 # ---------------------------------------------------------------------------
-# fused-KV decode kernel: one (bs, KV*2, hd) block, ONE DMA per block
+# fused-KV decode kernel: one (2, KV, bs, hd) block, ONE DMA per block
 # ---------------------------------------------------------------------------
 
 
@@ -208,47 +252,39 @@ def _fused_kernel(tables_ref, lengths_ref, q_ref, kv_ref, o_ref,
                   W: int, Bs: int, M: int):
     b = pl.program_id(0)
     mi = pl.program_id(1)
-    nm = pl.num_programs(1)
     length = lengths_ref[b]
 
     @pl.when(mi == 0)
     def _init():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
+        _init_state(m_sc, l_sc, acc_sc)
 
     blk_start = mi * bs
     resident = tables_ref[_table_index(b, mi, W=W, Bs=Bs, M=M)] >= 0
-    visible = blk_start < length
-    if window is not None:
-        visible = jnp.logical_and(visible, blk_start + bs > length - window)
 
-    @pl.when(jnp.logical_and(resident, visible))
+    @pl.when(jnp.logical_and(resident,
+                             _block_visible(blk_start, length, bs, window)))
     def _step():
         q = q_ref[0].astype(jnp.float32)              # (KV, G, hd)
-        kv = kv_ref[0].astype(jnp.float32)            # (bs, KV*2, hd)
-        _flash_block_step(q, kv[:, 0::2, :], kv[:, 1::2, :],
-                          blk_start, length, window, m_sc, l_sc, acc_sc)
+        k = kv_ref[0, 0].astype(jnp.float32)          # (KV, bs, hd)
+        v = kv_ref[0, 1].astype(jnp.float32)
+        _flash_block_step(q, k, v, blk_start, length, window,
+                          m_sc, l_sc, acc_sc)
 
-    @pl.when(mi == nm - 1)
-    def _finalize():
-        out = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
-        o_ref[0] = out.astype(o_ref.dtype)
+    @pl.when(mi == pl.num_programs(1) - 1)
+    def _done():
+        _finalize(o_ref, l_sc, acc_sc)
 
 
 def paged_attention_fused_fwd(q: jax.Array, kv_pool: jax.Array,
                               shard_tables: jax.Array, lengths: jax.Array, *,
                               window: int | None = None,
                               interpret: bool = False) -> jax.Array:
-    """q: (B, KV, G, hd); fused pool: (N, bs, KV*2, hd) head-interleaved
-    (K even, V odd); shard_tables: (W, Bs, M); lengths: (B,) →
-    (B, KV, G, hd).  Bit-identical to :func:`paged_attention_fwd` on the
-    split views of the same pool."""
+    """q: (B, KV, G, hd); fused pool: (N, 2, KV, bs, hd) (K at index 0,
+    V at 1 of axis 1); shard_tables: (W, Bs, M); lengths: (B,) →
+    (B, KV, G, hd).  Bit-identical to :func:`paged_attention_fwd` on
+    ``kv_pool[:, 0]`` / ``kv_pool[:, 1]``."""
     B, KV, G, hd = q.shape
-    N, bs, KV2, _ = kv_pool.shape
-    if KV2 != 2 * KV:
-        raise ValueError(f"fused pool has {KV2} interleaved heads, "
-                         f"query expects {2 * KV}")
+    bs = _check_fused(kv_pool, KV, hd)
     W, Bs, M = shard_tables.shape
     if W * Bs < B:
         raise ValueError(f"shard stack covers {W * Bs} slots < batch {B}")
@@ -258,21 +294,17 @@ def paged_attention_fused_fwd(q: jax.Array, kv_pool: jax.Array,
 
     def kv_map(b, m, tables_ref, lengths_ref):
         idx = _table_index(b, m, W=W, Bs=Bs, M=M)
-        return (jnp.maximum(tables_ref[idx], 0), 0, 0, 0)
+        return (jnp.maximum(tables_ref[idx], 0), 0, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, M),
         in_specs=[
             pl.BlockSpec((1, KV, G, hd), q_map),
-            pl.BlockSpec((1, bs, KV2, hd), kv_map),
+            pl.BlockSpec((1, 2, KV, bs, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, KV, G, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, hd), jnp.float32),
-        ],
+        scratch_shapes=_decode_scratch(KV, G, hd),
     )
     kern = functools.partial(_fused_kernel, bs=bs, window=window,
                              W=W, Bs=Bs, M=M)
@@ -280,8 +312,7 @@ def paged_attention_fused_fwd(q: jax.Array, kv_pool: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(shard_tables.reshape(-1), lengths, q, kv_pool)
 
@@ -298,7 +329,7 @@ def _fused_pipelined_kernel(tables_ref, lengths_ref, q_ref, kv_hbm_ref,
     """The fused kernel with the block walk's DMA issued by hand.
 
     The fused pool stays in ``ANY`` (HBM) memory; a revolving ``(depth,
-    bs, KV*2, hd)`` VMEM buffer holds the next ``depth`` blocks in
+    2, KV, bs, hd)`` VMEM buffer holds the next ``depth`` blocks in
     flight, so block ``mi + depth``'s copy overlaps block ``mi``'s flash
     step.  Copy starts/waits are balanced per sequence row: ``min(depth,
     nm)`` warm-up starts at ``mi == 0``, one wait + (if another block
@@ -317,10 +348,8 @@ def _fused_pipelined_kernel(tables_ref, lengths_ref, q_ref, kv_hbm_ref,
 
     @pl.when(mi == 0)
     def _init():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
-        for j in range(min(depth, nm)):               # warm-up fills
+        _init_state(m_sc, l_sc, acc_sc)
+        for j in range(min(depth, M)):                # warm-up fills
             copy(j, j).start()
 
     slot = jax.lax.rem(mi, depth)
@@ -328,25 +357,23 @@ def _fused_pipelined_kernel(tables_ref, lengths_ref, q_ref, kv_hbm_ref,
 
     blk_start = mi * bs
     resident = tables_ref[_table_index(b, mi, W=W, Bs=Bs, M=M)] >= 0
-    visible = blk_start < length
-    if window is not None:
-        visible = jnp.logical_and(visible, blk_start + bs > length - window)
 
-    @pl.when(jnp.logical_and(resident, visible))
+    @pl.when(jnp.logical_and(resident,
+                             _block_visible(blk_start, length, bs, window)))
     def _step():
         q = q_ref[0].astype(jnp.float32)              # (KV, G, hd)
-        kv = buf[slot].astype(jnp.float32)            # (bs, KV*2, hd)
-        _flash_block_step(q, kv[:, 0::2, :], kv[:, 1::2, :],
-                          blk_start, length, window, m_sc, l_sc, acc_sc)
+        k = buf[slot, 0].astype(jnp.float32)          # (KV, bs, hd)
+        v = buf[slot, 1].astype(jnp.float32)
+        _flash_block_step(q, k, v, blk_start, length, window,
+                          m_sc, l_sc, acc_sc)
 
     @pl.when(mi + depth < nm)
     def _prefetch_next():
         copy(mi + depth, slot).start()
 
     @pl.when(mi == nm - 1)
-    def _finalize():
-        out = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
-        o_ref[0] = out.astype(o_ref.dtype)
+    def _done():
+        _finalize(o_ref, l_sc, acc_sc)
 
 
 def paged_attention_fused_pipelined_fwd(
@@ -357,10 +384,7 @@ def paged_attention_fused_pipelined_fwd(
     manual DMA pipelining.  Bit-identical output — pipelining only moves
     *when* bytes arrive in VMEM, never what the flash step computes."""
     B, KV, G, hd = q.shape
-    N, bs, KV2, _ = kv_pool.shape
-    if KV2 != 2 * KV:
-        raise ValueError(f"fused pool has {KV2} interleaved heads, "
-                         f"query expects {2 * KV}")
+    bs = _check_fused(kv_pool, KV, hd)
     if buffer_depth < 1:
         raise ValueError(f"buffer_depth must be >= 1, got {buffer_depth}")
     W, Bs, M = shard_tables.shape
@@ -375,14 +399,11 @@ def paged_attention_fused_pipelined_fwd(
         grid=(B, M),
         in_specs=[
             pl.BlockSpec((1, KV, G, hd), q_map),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # whole fused pool
+            pl.BlockSpec(memory_space=pl.ANY),        # whole fused pool
         ],
         out_specs=pl.BlockSpec((1, KV, G, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, hd), jnp.float32),
-            pltpu.VMEM((buffer_depth, bs, KV2, hd), kv_pool.dtype),
+        scratch_shapes=_decode_scratch(KV, G, hd) + [
+            pltpu.VMEM((buffer_depth, 2, KV, bs, hd), kv_pool.dtype),
             pltpu.SemaphoreType.DMA((buffer_depth,)),
         ],
     )
@@ -392,8 +413,7 @@ def paged_attention_fused_pipelined_fwd(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(shard_tables.reshape(-1), lengths, q, kv_pool)
 
@@ -408,7 +428,6 @@ def _ragged_kernel(tables_ref, tile_row_ref, tile_pos_ref, kv_lens_ref,
                    bs: int, window: int | None, W: int, Bs: int, M: int):
     t = pl.program_id(0)
     mi = pl.program_id(1)
-    nm = pl.num_programs(1)
     row = tile_row_ref[t]                             # batch slot, -1 = pad
     qpos0 = tile_pos_ref[t]                           # tile's first q pos
     slot = jnp.maximum(row, 0)
@@ -416,9 +435,7 @@ def _ragged_kernel(tables_ref, tile_row_ref, tile_pos_ref, kv_lens_ref,
 
     @pl.when(mi == 0)
     def _init():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
+        _init_state(m_sc, l_sc, acc_sc)
 
     blk_start = mi * bs
     resident = tables_ref[_table_index(slot, mi, W=W, Bs=Bs, M=M)] >= 0
@@ -430,35 +447,22 @@ def _ragged_kernel(tables_ref, tile_row_ref, tile_pos_ref, kv_lens_ref,
 
     @pl.when(jnp.logical_and(resident, visible))
     def _step():
-        q = q_ref[...].astype(jnp.float32)            # (QT, KV, G, hd)
-        kv = kv_ref[0].astype(jnp.float32)            # (bs, KV*2, hd)
-        k = kv[:, 0::2, :]
-        v = kv[:, 1::2, :]
-        hd = q.shape[-1]
-        s = jnp.einsum("qkgd,skd->kgqs", q, k,
-                       preferred_element_type=jnp.float32) * (hd ** -0.5)
-        qpos = qpos0 + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)                    # (KV, G, QT, bs)
-        kpos = blk_start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 3)
+        q = q_ref[0].astype(jnp.float32)              # (KV, G*QT, hd)
+        k = kv_ref[0, 0].astype(jnp.float32)          # (KV, bs, hd)
+        v = kv_ref[0, 1].astype(jnp.float32)
+        s = _scores(q, k)                             # (KV, G*QT, bs)
+        # row r holds query token r % QT of the tile (head group r // QT)
+        qpos = qpos0 + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1), QT)
+        kpos = blk_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         mask = jnp.logical_and(kpos <= qpos, kpos < kv_len)
         if window is not None:
             mask = jnp.logical_and(mask, kpos > qpos - window)
-        s = jnp.where(mask, s, NEG_INF)
+        _online_softmax(jnp.where(mask, s, NEG_INF), v, m_sc, l_sc, acc_sc)
 
-        m_prev = m_sc[...]                            # (KV, G, QT, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                        # (KV, G, QT, bs)
-        scale = jnp.exp(m_prev - m_new)
-        l_sc[...] = l_sc[...] * scale + p.sum(axis=-1, keepdims=True)
-        acc_sc[...] = acc_sc[...] * scale + jnp.einsum(
-            "kgqs,skd->kgqd", p, v, preferred_element_type=jnp.float32)
-        m_sc[...] = m_new
-
-    @pl.when(mi == nm - 1)
-    def _finalize():
-        out = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
-        o_ref[...] = out.transpose(2, 0, 1, 3).astype(o_ref.dtype)
+    @pl.when(mi == pl.num_programs(1) - 1)
+    def _done():
+        _finalize(o_ref, l_sc, acc_sc)
 
 
 def ragged_fused_fwd(q: jax.Array, kv_pool: jax.Array,
@@ -466,25 +470,23 @@ def ragged_fused_fwd(q: jax.Array, kv_pool: jax.Array,
                      tile_pos: jax.Array, kv_lens: jax.Array, *,
                      window: int | None = None,
                      interpret: bool = False) -> jax.Array:
-    """Ragged fused-KV attention over packed query rows.
+    """Ragged fused-KV attention over packed query tiles.
 
-    q: (T, KV, G, hd) packed queries, T a multiple of :data:`QT`, each
-    row's segment padded so tiles never span rows; fused pool: (N, bs,
-    KV*2, hd); shard_tables: (W, Bs, M); tile_row: (T // QT,) batch slot
-    per tile (-1 = padding tile, skipped); tile_pos: (T // QT,) global
-    position of each tile's first query; kv_lens: (W * Bs,) kv length
-    per batch slot → (T, KV, G, hd).  Padded rows produce finite
-    garbage (``NEG_INF`` is finite) and are dropped by the caller.
+    q: (T // QT, KV, G * QT, hd) — the packed queries, one ``(KV, G*QT,
+    hd)`` slab per tile of :data:`QT` tokens, row ``g * QT + t`` holding
+    query head ``g`` (of its KV group) of the tile's token ``t``; each
+    row's segment is padded so tiles never span rows.  fused pool: (N,
+    2, KV, bs, hd); shard_tables: (W, Bs, M); tile_row: (T // QT,) batch
+    slot per tile (-1 = padding tile, skipped); tile_pos: (T // QT,)
+    global position of each tile's first query; kv_lens: (W * Bs,) kv
+    length per batch slot → same layout as ``q``.  Padded rows produce
+    finite garbage (``NEG_INF`` is finite) and are dropped by the caller.
     """
-    T, KV, G, hd = q.shape
-    if T % QT:
-        raise ValueError(f"packed length {T} not a multiple of QT={QT}")
-    N, bs, KV2, _ = kv_pool.shape
-    if KV2 != 2 * KV:
-        raise ValueError(f"fused pool has {KV2} interleaved heads, "
-                         f"query expects {2 * KV}")
+    tiles, KV, R, hd = q.shape
+    if R % QT:
+        raise ValueError(f"tile rows {R} not a multiple of QT={QT}")
+    bs = _check_fused(kv_pool, KV, hd)
     W, Bs, M = shard_tables.shape
-    tiles = T // QT
 
     def q_map(t, m, tables_ref, tile_row_ref, tile_pos_ref, kv_lens_ref):
         return (t, 0, 0, 0)
@@ -492,30 +494,25 @@ def ragged_fused_fwd(q: jax.Array, kv_pool: jax.Array,
     def kv_map(t, m, tables_ref, tile_row_ref, tile_pos_ref, kv_lens_ref):
         slot = jnp.maximum(tile_row_ref[t], 0)
         idx = _table_index(slot, m, W=W, Bs=Bs, M=M)
-        return (jnp.maximum(tables_ref[idx], 0), 0, 0, 0)
+        return (jnp.maximum(tables_ref[idx], 0), 0, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(tiles, M),
         in_specs=[
-            pl.BlockSpec((QT, KV, G, hd), q_map),
-            pl.BlockSpec((1, bs, KV2, hd), kv_map),
+            pl.BlockSpec((1, KV, R, hd), q_map),
+            pl.BlockSpec((1, 2, KV, bs, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((QT, KV, G, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((KV, G, QT, 1), jnp.float32),
-            pltpu.VMEM((KV, G, QT, 1), jnp.float32),
-            pltpu.VMEM((KV, G, QT, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, KV, R, hd), q_map),
+        scratch_shapes=_decode_scratch(KV, R, hd),
     )
     kern = functools.partial(_ragged_kernel, bs=bs, window=window,
                              W=W, Bs=Bs, M=M)
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, KV, G, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(shard_tables.reshape(-1), tile_row, tile_pos, kv_lens,
       q, kv_pool)

@@ -5,13 +5,12 @@ monolithic split-pool oracle; ``paged_decode_attention_sharded_ref``
 consumes the device-native ``(W, Bs, M)`` interleaved shard stack by
 assembling the monolithic view *inside the traced graph* and deferring
 to it; ``paged_decode_attention_fused_ref`` does the same for the
-head-interleaved fused pool (K even, V odd) by splitting the strided
-views; and ``ragged_fused_ref`` is the oracle for the ragged kernel —
+fused ``(N, 2, KV, bs, hd)`` pool by splitting it into token-major K and
+V; and ``ragged_fused_ref`` is the oracle for the ragged kernel —
 packed mixed prefill + decode query rows, per-element causal / length /
 window / hole masking, any table layout.  The Pallas kernels must match
 all of them on the same inputs (and the fused/pipelined kernels must
-match the split kernel *bit for bit* — the interleave is a pure
-permutation).
+match the split kernel *bit for bit* — fusing is a pure permutation).
 """
 
 from __future__ import annotations
@@ -38,8 +37,8 @@ def paged_decode_attention_sharded_ref(
 def paged_decode_attention_fused_ref(
         q: jax.Array, kv_pool: jax.Array, shard_tables: jax.Array,
         lengths: jax.Array, window: int | None = None) -> jax.Array:
-    """Oracle for the fused-pool kernel path: split the interleaved pool
-    and defer to the split oracle."""
+    """Oracle for the fused-pool kernel path: split the fused pool and
+    defer to the split oracle."""
     k_pool, v_pool = split_fused_kv(kv_pool)
     return paged_decode_attention_sharded_ref(q, k_pool, v_pool,
                                               shard_tables, lengths,
@@ -53,7 +52,7 @@ def ragged_fused_ref(q: jax.Array, kv_pool: jax.Array, tables: jax.Array,
     """Oracle for the ragged fused kernel.
 
     q:          (T, H, hd)   packed query rows (padding rows included)
-    kv_pool:    (N, bs, KV*2, hd) head-interleaved fused pool
+    kv_pool:    (N, 2, KV, bs, hd) fused pool
     tables:     (B, M) or (W, Bs, M)
     token_row:  (T,) batch slot per packed token (-1 = padding)
     token_pos:  (T,) global position per packed token

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sl_ref,
@@ -110,8 +109,8 @@ def rwkv6_scan_fwd(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((B, nH, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u, S0)
     return y, s_last

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell the step function (train_step / prefill / serve_step) is
@@ -22,6 +19,7 @@ Usage:
 import argparse
 import functools
 import json
+import os
 import time
 import traceback
 
@@ -310,6 +308,10 @@ def iter_cells(multi_pod: bool):
 
 
 def main():
+    # 512 fake host devices for the production meshes; XLA reads the flag
+    # when the first backend starts, so setting it here (before any
+    # device query) is enough and importing this module changes nothing
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(SHAPES))

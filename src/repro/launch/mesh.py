@@ -5,18 +5,7 @@ never touches jax device state (the dry-run sets device-count flags first).
 from __future__ import annotations
 
 import jax
-
-
-def mesh_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where the installed jax supports it.
-
-    ``jax.sharding.AxisType`` only exists on newer jax; older releases
-    default every axis to Auto anyway, so omitting the kwarg is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -34,11 +23,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     import numpy as np
     return jax.sharding.Mesh(
         np.asarray(devs[:n]).reshape(shape), axes,
-        **mesh_axis_kwargs(len(axes)))
+        axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (fake or real) devices exist — used by
     tests and CPU examples, same axis names as production."""
     return jax.make_mesh(
-        (data, model), ("data", "model"), **mesh_axis_kwargs(2))
+        (data, model), ("data", "model"),
+        axis_types=(AxisType.Auto,) * 2)

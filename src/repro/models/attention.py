@@ -319,21 +319,23 @@ def lookup_slot_blocks(tables: jax.Array, slots: jax.Array,
 
 
 def fuse_kv(k: jax.Array, v: jax.Array) -> jax.Array:
-    """Head-interleave K and V along the head axis (K even, V odd).
+    """Fuse token-major K and V into the paged-attention pool layout.
 
-    ``(..., KV, hd) × 2 → (..., KV*2, hd)`` — the fused-pool layout of
-    the paged-attention kernel, where one logical block is ONE
-    contiguous DMA instead of two.  Pure permutation: ``split_fused_kv``
-    inverts it bit for bit.
+    ``(..., S, KV, hd) × 2 → (..., 2, KV, S, hd)``: K at index 0 and V at
+    index 1 of an untiled axis, each head's ``(S, hd)`` tile contiguous.
+    With ``S`` the block size this is one fused pool block — ONE
+    contiguous DMA instead of two, and the kernel picks K and V by a
+    static index.  Pure permutation: ``split_fused_kv`` inverts it bit
+    for bit.
     """
-    return jnp.stack([k, v], axis=-2).reshape(
-        *k.shape[:-2], 2 * k.shape[-2], k.shape[-1])
+    return jnp.stack([k, v], axis=-4).swapaxes(-3, -2)
 
 
 def split_fused_kv(kv: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Strided K/V views of a head-interleaved fused array (inverse of
-    :func:`fuse_kv`)."""
-    return kv[..., 0::2, :], kv[..., 1::2, :]
+    """Token-major ``(..., S, KV, hd)`` K and V of a fused ``(..., 2, KV,
+    S, hd)`` array (inverse of :func:`fuse_kv`)."""
+    kv = kv.swapaxes(-3, -2)
+    return kv[..., 0, :, :, :], kv[..., 1, :, :, :]
 
 
 def paged_decode_attention_ref(q: jax.Array, k_pool: jax.Array,

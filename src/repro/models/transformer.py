@@ -7,9 +7,9 @@ parameters (HLO stays small at 512 devices).  Each layer = mixer + ffn
 
 Decode state is a dict pytree of per-kind cache pools:
 
-  kv:    fused k/v (L_attn, N, bs, KV*2, hd) paged GQA cache, head-
-         interleaved (K even, V odd) so one logical block is ONE
-         contiguous DMA (writes past the pool scatter-drop)
+  kv:    fused k/v (L_attn, N, 2, KV, bs, hd) paged GQA cache, K and V
+         side by side on axis 2 so one logical block is ONE contiguous
+         DMA (writes past the pool scatter-drop)
   mla:   c/rope  (L, N+1, bs, rank|rope_hd)  paged latent cache
   mamba: conv/ssm (L_m, B, K-1, DI) / (L_m, B, DI, dstate)
   rwkv:  last_x/wkv (L, B, D) / (L, B, nH, 64, 64)
@@ -104,10 +104,13 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
         n_blocks = (cfg.n_layers - prefix) // period
         body = []
         for j in range(period):
-            per_block = [_init_layer(keys[prefix + b * period + j], cfg,
-                                     prefix + b * period + j, dtype)
-                         for b in range(n_blocks)]
-            body.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_block))
+            # vmap over the blocks' keys builds the stacked body directly
+            # (same values as stacking per-layer inits, without holding
+            # the unstacked copy alongside it)
+            ids = [prefix + b * period + j for b in range(n_blocks)]
+            body.append(jax.vmap(
+                lambda k, i=ids[0]: _init_layer(k, cfg, i, dtype))(
+                    keys[jnp.asarray(ids)]))
         params["body"] = tuple(body)
     else:
         params["body"] = ()
@@ -363,9 +366,9 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
         spec["mla_c"] = ((L, N, bs, m.kv_lora_rank), dtype)
         spec["mla_rope"] = ((L, N, bs, m.rope_head_dim), dtype)
     if n_attn:
-        # fused head-interleaved K/V pool: K on even, V on odd head
-        # indices — one block, one DMA (see kernels/paged_attention)
-        spec["kv"] = ((n_attn, N, bs, 2 * KV, hd), dtype)
+        # fused K/V pool: each block holds K then V, head-major — one
+        # block, one DMA (see kernels/paged_attention)
+        spec["kv"] = ((n_attn, N, 2, KV, bs, hd), dtype)
     if n_mamba:
         mm = cfg.mamba
         spec["conv"] = ((n_mamba, batch, mm.d_conv - 1, cfg.d_inner), dtype)
@@ -430,7 +433,7 @@ def _paged_attn(q, kv_pool, tables, lengths, *, page_impl, window,
                 mesh=None, batch_axes=(), seq_axes=()):
     """Dispatch one decode-attention step over either table layout.
 
-    ``kv_pool`` is the fused head-interleaved ``(N, bs, KV*2, hd)`` pool;
+    ``kv_pool`` is the fused ``(N, 2, KV, bs, hd)`` pool;
     ``tables`` is the monolithic ``(B, M)`` table or the device-native
     ``(W, Bs, M)`` shard stack.  The Pallas kernel consumes both
     directly (shard-native page walk over one-DMA fused blocks, with the
@@ -443,8 +446,8 @@ def _paged_attn(q, kv_pool, tables, lengths, *, page_impl, window,
     if page_impl in ("pallas", "pallas_interpret"):
         from repro.kernels.paged_attention import autotune as pa_autotune
         from repro.kernels.paged_attention import ops as pa_ops
-        _, bs, KV2, hd = kv_pool.shape
-        tuned = pa_autotune.get_tuning(KV2 // 2, hd, bs)
+        _, _, KV, bs, hd = kv_pool.shape
+        tuned = pa_autotune.get_tuning(KV, hd, bs)
         return pa_ops.paged_attention(
             q, kv_pool, tables, lengths, window=window,
             buffer_depth=tuned.buffer_depth,
@@ -462,19 +465,29 @@ def _paged_attn(q, kv_pool, tables, lengths, *, page_impl, window,
         lengths, window=window)
 
 
-def _write_token_kv(pool, tables, lengths, new, bs):
-    """Scatter one token's cache row into the paged pool.
-
-    pool: (N, bs, ...) ; new: (B, ...) ; position = lengths (0-based index
-    of the incoming token).  Non-resident (<0) table entries drop the write
-    (mapped out of bounds — negative indices would *wrap*, not drop).
-    """
-    B = new.shape[0]
+def _token_blocks(tables, lengths, bs, n_blocks):
+    """Physical block and in-block offset of each slot's incoming token
+    (0-based position ``lengths``).  Non-resident (<0) table entries map
+    to ``n_blocks``, past the pool end, so a ``mode="drop"`` scatter
+    drops them (negative indices would *wrap*, not drop)."""
     blk_idx = lengths // bs                          # (B,)
-    off = lengths % bs
     phys = attn_mod.lookup_slot_blocks(
-        tables, jnp.arange(B), jnp.minimum(blk_idx, tables.shape[-1] - 1))
-    phys = jnp.where(phys >= 0, phys, pool.shape[0])
+        tables, jnp.arange(lengths.shape[0]),
+        jnp.minimum(blk_idx, tables.shape[-1] - 1))
+    return jnp.where(phys >= 0, phys, n_blocks), lengths % bs
+
+
+def _write_fused_kv(kvp, phys, off, rows):
+    """Scatter token rows (T, 2, KV, hd) into the fused (N, 2, KV, bs, hd)
+    layer pool: row t lands in block ``phys[t]`` at in-block offset
+    ``off[t]``; ``phys[t] >= N`` drops it."""
+    return kvp.at[phys, :, :, off].set(rows.astype(kvp.dtype), mode="drop")
+
+
+def _write_token_kv(pool, tables, lengths, new, bs):
+    """Scatter one token's row new (B, ...) into a latent (N, bs, ...)
+    paged pool."""
+    phys, off = _token_blocks(tables, lengths, bs, pool.shape[0])
     return pool.at[phys, off].set(new.astype(pool.dtype), mode="drop")
 
 
@@ -538,10 +551,14 @@ def decode_step(params, cfg: ModelConfig, state: dict, tokens: jax.Array, *,
             a = attn_ids.index(i)
             h = rms_norm(x[:, None], lp["mix"]["norm"], cfg.norm_eps)
             q, k, v = attn_mod.qkv_proj(lp["mix"], h, cfg, positions)
-            st["kv"] = _write_token_kv_stacked(
-                st["kv"], a, st["tables"], pos,
-                attn_mod.fuse_kv(k[:, 0], v[:, 0]), bs)
-            o = _paged_attn(q[:, 0], st["kv"][a], st["tables"],
+            # scatter into the layer slice, then re-insert it (see
+            # _write_token_kv_stacked)
+            kvp = _write_fused_kv(
+                st["kv"][a],
+                *_token_blocks(st["tables"], pos, bs, st["kv"].shape[1]),
+                jnp.stack([k[:, 0], v[:, 0]], axis=1))
+            st["kv"] = st["kv"].at[a].set(kvp)
+            o = _paged_attn(q[:, 0], kvp, st["tables"],
                             pos + 1, page_impl=page_impl,
                             window=cfg.attn.window, mesh=mesh,
                             batch_axes=batch_axes, seq_axes=seq_axes)
@@ -693,13 +710,17 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array, state: dict, *,
     # its rows into the pools as it runs, and the pools ride the scan carry.
     tables_const = attn_mod.assemble_shard_tables(st["tables"])[:B]
 
-    def scatter_seq(pool, seq):
-        """seq: (B, S_tot, ...) → paged pool (N, bs, ...); <0 entries drop."""
+    def to_blocks(seq):
+        """seq: (B, S_tot, ...) → per-block rows (B * M_used, bs, ...)."""
         pad = (-S_tot) % bs
         if pad:
             seq = jnp.pad(seq, ((0, 0), (0, pad)) + ((0, 0),) * (seq.ndim - 2))
-        M_used = seq.shape[1] // bs
-        seq = seq.reshape((B * M_used, bs) + seq.shape[2:])
+        return seq.reshape((-1, bs) + seq.shape[2:])
+
+    def scatter_seq(pool, seq):
+        """seq: (B * M_used, ...) pool blocks → paged pool (N, ...); <0
+        entries drop."""
+        M_used = seq.shape[0] // B
         tab = tables_const[:, :M_used].reshape(-1)
         if mesh is not None and "model" in mesh.axis_names:
             from repro.distributed.collectives import scatter_seq_sp
@@ -722,12 +743,14 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array, state: dict, *,
     def write_caches(stt, i_dyn, a_dyn, m_dyn, c):
         if "kv" in c:
             k, v = c["kv"]
-            stt["kv"] = _dyn_scatter(stt["kv"], a_dyn,
-                                     attn_mod.fuse_kv(k, v))
+            stt["kv"] = _dyn_scatter(
+                stt["kv"], a_dyn, attn_mod.fuse_kv(to_blocks(k),
+                                                   to_blocks(v)))
         if "mla" in c and c["mla"] is not None:
             ckv, krope = c["mla"]
-            stt["mla_c"] = _dyn_scatter(stt["mla_c"], i_dyn, ckv)
-            stt["mla_rope"] = _dyn_scatter(stt["mla_rope"], i_dyn, krope)
+            stt["mla_c"] = _dyn_scatter(stt["mla_c"], i_dyn, to_blocks(ckv))
+            stt["mla_rope"] = _dyn_scatter(stt["mla_rope"], i_dyn,
+                                           to_blocks(krope))
         if "mamba" in c:
             cs, ss = c["mamba"]
             stt["conv"] = _dyn_write(stt["conv"], m_dyn,
@@ -833,20 +856,25 @@ def prefill_chunk(params, cfg: ModelConfig, tokens: jax.Array, state: dict,
                                    jnp.minimum(cols, M - 1), axis=1),
                           -1)                                 # (B, Cb)
 
-    def scatter_chunk(pool, seq):
-        """seq (B, C, ...) → the chunk's pool rows; <0 entries drop."""
-        seq = seq.reshape((B * Cb, bs) + seq.shape[2:])
+    def scatter_chunk(pool, k, v):
+        """k/v (B, C, KV, hd) → the chunk's fused pool blocks; <0 entries
+        drop."""
+        blocks = attn_mod.fuse_kv(k.reshape((B * Cb, bs) + k.shape[2:]),
+                                  v.reshape((B * Cb, bs) + v.shape[2:]))
         tab = chunk_tab.reshape(-1)
         neg = jnp.where(tab >= 0, tab, pool.shape[0])
-        return pool.at[neg].set(seq.astype(pool.dtype), mode="drop")
+        return pool.at[neg].set(blocks.astype(pool.dtype), mode="drop")
 
     def gather_window(pool):
-        """Full-window keys (B, M*bs, ...) — unallocated (-1) columns
-        gather arbitrary resident rows; they sit past the causal frontier
-        and the attention mask zeroes them exactly."""
+        """Full-window keys and values, (B, M*bs, KV, hd) each —
+        unallocated (-1) columns gather arbitrary resident rows; they sit
+        past the causal frontier and the attention mask zeroes them
+        exactly."""
         rows = jnp.take(pool, jnp.maximum(tables_const, 0).reshape(-1),
                         axis=0)
-        return rows.reshape((B, M * bs) + pool.shape[2:])
+        k, v = attn_mod.split_fused_kv(rows)         # (B*M, bs, KV, hd)
+        return (k.reshape((B, M * bs) + k.shape[2:]),
+                v.reshape((B, M * bs) + v.shape[2:]))
 
     def run_layer(lp, x, pools, a_dyn, sig):
         _, ffn = sig
@@ -854,11 +882,11 @@ def prefill_chunk(params, cfg: ModelConfig, tokens: jax.Array, state: dict,
         q, k, v = attn_mod.qkv_proj(lp["mix"], h, cfg, positions)
         kvp = jax.lax.dynamic_index_in_dim(pools["kv"], a_dyn, 0,
                                            keepdims=False)
-        kvp = scatter_chunk(kvp, attn_mod.fuse_kv(k, v))
+        kvp = scatter_chunk(kvp, k, v)
         pools = dict(pools)
         pools["kv"] = jax.lax.dynamic_update_index_in_dim(pools["kv"], kvp,
                                                           a_dyn, 0)
-        kw, vw = attn_mod.split_fused_kv(gather_window(kvp))
+        kw, vw = gather_window(kvp)
         o = attn_mod.chunked_attention_fwd(
             q, kw, vw, causal=True,
             window=cfg.attn.window, q_offset=start)
@@ -946,7 +974,7 @@ def ragged_step(params, cfg: ModelConfig, state: dict, tokens: jax.Array,
     drop = valid & (phys >= 0) & (blk_idx < M)
 
     def ragged_attn(q, kvp):
-        """q: (T, H, hd) over the fused (N, bs, KV*2, hd) layer pool."""
+        """q: (T, H, hd) over the fused (N, 2, KV, bs, hd) layer pool."""
         if page_impl in ("pallas", "pallas_interpret"):
             from repro.kernels.paged_attention import ops as pa_ops
             return pa_ops.ragged_paged_attention(
@@ -962,11 +990,11 @@ def ragged_step(params, cfg: ModelConfig, state: dict, tokens: jax.Array,
         _, ffn = sig
         h = rms_norm(x[None], lp["mix"]["norm"], cfg.norm_eps)
         q, k, v = attn_mod.qkv_proj(lp["mix"], h, cfg, positions)
-        rows = attn_mod.fuse_kv(k[0], v[0])              # (T, KV*2, hd)
+        rows = jnp.stack([k[0], v[0]], axis=1)           # (T, 2, KV, hd)
         kvp = jax.lax.dynamic_index_in_dim(pools["kv"], a_dyn, 0,
                                            keepdims=False)
         tgt = jnp.where(drop, jnp.maximum(phys, 0), kvp.shape[0])
-        kvp = kvp.at[tgt, off].set(rows.astype(kvp.dtype), mode="drop")
+        kvp = _write_fused_kv(kvp, tgt, off, rows)
         pools = dict(pools)
         pools["kv"] = jax.lax.dynamic_update_index_in_dim(
             pools["kv"], kvp, a_dyn, 0)

@@ -28,6 +28,7 @@ keeps the ``demand_pager_gave_up`` counter behaviour.
 from __future__ import annotations
 
 import time
+import warnings
 import zlib
 
 import jax
@@ -170,6 +171,17 @@ class Engine:
         # tests/test_chunked_prefill.py
         self._prefill_traces = 0
         self._prefill_chunk_traces = 0
+        if (config.prefix_sharing and config.fpr_enabled
+                and not self._chunked and jax.default_backend() == "tpu"):
+            # monolithic prefill compiles one program per padded prompt
+            # length, and on TPU those programs give a shared prefix's KV
+            # different low bits, so a sharer decodes other tokens than it
+            # would alone (PERF.md, Findings)
+            warnings.warn(
+                "prefix sharing with monolithic prefill is not "
+                "token-identical to unshared serving on TPU; use "
+                "chunked_prefill=True or prefix_sharing=False",
+                stacklevel=2)
         if self._chunked and self.governor is not None:
             self.governor.chunk_blocks = config.prefill_chunk
 
@@ -545,6 +557,11 @@ class Engine:
         toks = np.zeros((1, Sp), np.int32)
         toks[0, :S] = r.prompt
         tables = self.cache.slot_tables({0: r.mapping})[:1]
+        # Attached prefix blocks already hold their owner's KV, and running
+        # sharers read it.  A prompt of another length recomputes the same
+        # prefix in different low bits on TPU, so rewriting them would
+        # change the context of requests mid-decode: drop those writes.
+        tables = tables.at[0, :r.mapping.prefix_hits].set(-1)
         st = dict(self.cache.state)
         st["tables"] = tables
         st["lengths"] = jnp.zeros((1,), jnp.int32)

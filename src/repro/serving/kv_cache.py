@@ -130,7 +130,7 @@ class PagedKVCache:
         # swap "device": evicted block contents round-trip through host
         # memory (the storage behind the page cache; latency is real)
         self._swap_store: dict = {}
-        # "kv" is the fused head-interleaved K/V pool — swap, COW
+        # "kv" is the fused K/V pool — swap, COW
         # divergence and shard refresh all move ONE contiguous array per
         # block instead of separate K and V halves
         self._pool_keys = [k for k in self.state

@@ -11,7 +11,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.launch.mesh import mesh_axis_kwargs  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 
 def check_sp_paged_attention(mesh):
@@ -97,7 +97,7 @@ def check_elastic_reshard(mesh):
         mgr.save(1, placed)
         for shape, names in (((2, 4), ("data", "model")),
                              ((8, 1), ("data", "model"))):
-            mesh2 = jax.make_mesh(shape, names, **mesh_axis_kwargs(2))
+            mesh2 = jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * 2)
             specs = {"w": P("model", "data"), "b": P(None)}
             back = mgr.restore(1, tree, mesh=mesh2, specs=specs)
             np.testing.assert_array_equal(np.asarray(back["w"]),
@@ -109,7 +109,7 @@ def check_elastic_reshard(mesh):
 
 def check_pipeline():
     from repro.distributed.pipeline import pipeline_apply
-    mesh = jax.make_mesh((8,), ("pipe",), **mesh_axis_kwargs(1))
+    mesh = jax.make_mesh((8,), ("pipe",), axis_types=(AxisType.Auto,))
     n_stages, n_micro, mb, d = 8, 4, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(2), n_stages)
     ws = jnp.stack([jax.random.normal(k, (d, d)) * 0.3 for k in ks])
@@ -158,7 +158,7 @@ def check_train_step_sharded(mesh):
 if __name__ == "__main__":
     assert jax.device_count() == 8, jax.device_count()
     mesh = jax.make_mesh((4, 2), ("data", "model"),
-                         **mesh_axis_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
     check_sp_paged_attention(mesh)
     check_vocab_parallel_embed(mesh)
     check_elastic_reshard(mesh)

@@ -83,6 +83,27 @@ def test_full_config_param_accounting(arch):
     assert total == cfg.param_count(), (total, cfg.param_count())
 
 
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_depth_cut_config(arch):
+    """``get_config(arch, n_layers=k)`` keeps the published widths and
+    the first ``k`` layers' mixer/ffn patterns, and init_params agrees
+    with its analytic parameter count."""
+    full = get_config(arch)
+    k = min(3, full.n_layers)
+    cut = get_config(arch, n_layers=k)
+    assert cut.n_layers == k
+    assert cut.mixers == full.mixers[:k] and cut.ffns == full.ffns[:k]
+    assert cut.with_(n_layers=full.n_layers, mixers=full.mixers,
+                     ffns=full.ffns) == full
+    shapes = jax.eval_shape(
+        lambda: tfm.init_params(KEY, cut, jnp.bfloat16))
+    total = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    assert total == cut.param_count()
+    for bad in (0, full.n_layers + 1):
+        with pytest.raises(ValueError, match="layers"):
+            get_config(arch, n_layers=bad)
+
+
 def test_assigned_param_counts_sane():
     """Full configs land near their nameplate sizes."""
     expect = {"deepseek-7b": (6e9, 8e9), "deepseek-v2-236b": (220e9, 250e9),

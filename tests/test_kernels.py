@@ -105,16 +105,26 @@ def _paged_case(B, H, KV, hd, bs, M, N, W, seed=0):
 ])
 def test_paged_attention(B, H, KV, hd, bs, M, N, win):
     """Fused kernel vs the jnp oracle AND bit-identical to the legacy
-    split-KV baseline (the interleave is a pure permutation)."""
+    split-KV baseline: the split kernel gets the K and V halves of the
+    fused pool, and both kernels hand the same (KV, bs, hd) tiles to one
+    shared flash step, so every float op runs in the same order."""
     q, kp, vp, kv, tables, _, lengths = _paged_case(B, H, KV, hd, bs, M, N, 1)
     got = paged_attention(q, kv, tables, lengths, window=win,
                           interpret=True)
     want = paged_decode_attention_ref(q, kp, vp, tables, lengths,
                                       window=win)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-    split = paged_attention_split(q, kp, vp, tables, lengths, window=win,
-                                  interpret=True)
+    split = paged_attention_split(q, kv[:, 0], kv[:, 1], tables, lengths,
+                                  window=win, interpret=True)
     assert jnp.array_equal(got, split), "fused kernel drifted from split"
+
+
+def test_microbench_kernel_identity():
+    """The microbench's kernel-mode identity gate (fused == split ==
+    every pipelined depth) runs and holds."""
+    from benchmarks.microbench import kernel_identity_case
+    out = kernel_identity_case()
+    assert out and all(out.values()), out
 
 
 @pytest.mark.parametrize("B,H,KV,hd,bs,M,N,win,W", [

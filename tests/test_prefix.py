@@ -350,8 +350,7 @@ def _run_sweep(ops):
 # ===================================================== engine differential
 @pytest.mark.slow
 class TestEngineSharing:
-    def _run(self, prompts, sharing, num_blocks=64, max_new=10,
-             admission=None, max_batch=4):
+    def _engine(self, sharing, num_blocks=64, admission=None, max_batch=4):
         import jax
         import jax.numpy as jnp
         from repro.models import transformer as tfm
@@ -362,9 +361,13 @@ class TestEngineSharing:
         cfg = ModelConfig(name="t", n_layers=1, d_model=32, n_heads=2,
                           n_kv_heads=1, d_ff=64, vocab=128, head_dim=16)
         params = tfm.init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
-        eng = Engine(cfg, params, config=EngineConfig(
+        return Engine(cfg, params, config=EngineConfig(
             num_blocks=num_blocks, max_batch=max_batch, max_seq_len=256,
             prefix_sharing=sharing, admission=admission))
+
+    def _run(self, prompts, sharing, num_blocks=64, max_new=10,
+             admission=None, max_batch=4):
+        eng = self._engine(sharing, num_blocks, admission, max_batch)
         for p in prompts:
             eng.submit(p, max_new_tokens=max_new)
         peak = 0
@@ -396,6 +399,47 @@ class TestEngineSharing:
         assert s1["fpr.prefix.in_set_violations"] == 0
         assert s1["fpr.allocs"] < e0.metrics.snapshot()["fpr.allocs"]
         assert e1.metrics.snapshot()["fpr.prefix.hit_rate"] > 0
+
+    def test_prefill_leaves_attached_blocks_alone(self):
+        """A sharer's monolithic prefill never rewrites the prefix blocks
+        it attached: their owner wrote them and running sharers read
+        them (on TPU a prompt of another length computes the same prefix
+        in different low bits)."""
+        rng = np.random.RandomState(7)
+        system = rng.randint(1, 128, size=128)     # one full block
+        owner, sharer = (np.concatenate([system, rng.randint(1, 128,
+                                                             size=n)])
+                         for n in (5, 9))
+        eng = self._engine(sharing=True)
+        eng.submit(owner, max_new_tokens=4)
+        eng.step()                                 # owner prefilled
+        blk = eng.sched.running[0].mapping.physical[0]
+        kv = eng.cache.state["kv"]
+        eng.cache.state["kv"] = kv.at[:, blk].set(7.0)
+        eng.submit(sharer, max_new_tokens=4)
+        eng.step()                                 # sharer attaches
+        m = next(r.mapping for r in eng.sched.running.values()
+                 if r.mapping.prefix_hits)
+        assert m.physical[0] == blk
+        assert bool((eng.cache.state["kv"][:, blk] == 7.0).all())
+
+    def test_monolithic_sharing_warns_on_tpu(self, monkeypatch):
+        """On TPU, per-slot (monolithic-prefill) sharing is not
+        token-identical to unshared serving, so the engine warns; it
+        stays silent off TPU and with sharing off."""
+        import warnings
+
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.warns(UserWarning, match="not token-identical"):
+            self._engine(sharing=True)
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            self._engine(sharing=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            self._engine(sharing=True)
 
     def test_ledger_admits_more_concurrent_shared_requests(self):
         """Admission commits *unique* blocks: at a fixed pool size the

@@ -227,7 +227,7 @@ def run(smoke: bool = False) -> dict:
     trace_summary = None
     for name, arrivals in workloads.items():
         eng = _make_engine()
-        collector = (TraceCollector(eng.bus) if name == "poisson"
+        collector = (TraceCollector(eng) if name == "poisson"
                      else None)
         outcome = _drive(eng, arrivals, SEED)
         report = _report(eng, outcome, arrivals)

@@ -237,48 +237,11 @@ class PreemptionResolved(Event):
     strategy: str
 
 
-# ----------------------------------------------------------------- lifecycle
-@dataclass(frozen=True)
-class PrefillChunkDone(Event):
-    """One fixed-shape prefill chunk landed for ``rid`` (tokens
-    ``[start, end)`` of the prompt are now in the cache).  The trace
-    layer stitches these into child spans of the request's root span."""
-
-    rid: int
-    start: int
-    end: int
-    step: int
-
-
-@dataclass(frozen=True)
-class RequestCompleted(Event):
-    """``rid`` finished decoding and released its mapping — the close of
-    the request's root span (admission opened it)."""
-
-    rid: int
-    n_tokens: int
-    step: int
-
-
-@dataclass(frozen=True)
-class StepCompleted(Event):
-    """One ``Engine.step`` finished.  ``wall_s`` is the step's wall time
-    (the span's duration — a tracer reconstructs the start as
-    ``now - wall_s``), ``tokens`` the decode tokens it produced,
-    ``running`` the occupied slots after the step."""
-
-    step: int
-    tokens: int
-    wall_s: float
-    running: int
-
-
 #: every event type this module defines, for docs/tests
 EVENT_TYPES = (FenceIssued, BlocksRecycled, ContextExit, BlocksShared,
                SharingExit, SwapDropped, ShardRefreshed, TopologyChanged,
                EvictionPass, AdmissionDecision, PreemptionStarted,
-               PreemptionResolved, PrefillChunkDone, RequestCompleted,
-               StepCompleted)
+               PreemptionResolved)
 
 
 Handler = Callable[[Event], None]
@@ -377,5 +340,4 @@ __all__ = ["Event", "EventBus", "EVENT_TYPES", "FenceIssued",
            "BlocksRecycled", "ContextExit", "BlocksShared", "SharingExit",
            "SwapDropped", "ShardRefreshed", "TopologyChanged",
            "EvictionPass", "AdmissionDecision", "PreemptionStarted",
-           "PreemptionResolved", "PrefillChunkDone", "RequestCompleted",
-           "StepCompleted"]
+           "PreemptionResolved"]

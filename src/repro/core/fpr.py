@@ -36,6 +36,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.allocator import BlockAllocator, BlockLease
 from repro.core.block_table import BlockTableStore, Mapping
 from repro.core.config import (FprConfig, validate_translation,
@@ -281,6 +282,8 @@ class FprMemoryManager:
         new_topo = self.fences.topology
         self.config = self.config.replace(
             islands=None if new_topo is None else new_topo.spec)
+        tracing.mark("fpr.reshard", old=old_num, new=new_num_workers,
+                     moved_slots=tuple(plan["moved_slots"]))
         if self.bus.wants(TopologyChanged):
             self.bus.publish(TopologyChanged(
                 old_num_workers=old_num,

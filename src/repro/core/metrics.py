@@ -121,6 +121,7 @@ STABLE_SCHEMA = (
     "device.table_shards",
     # engine.* — serving-loop counters
     "engine.completed",
+    "engine.decode_traces",
     "engine.demand_pager_gave_up",
     "engine.num_workers",
     # engine.obs.* — observability-plane self-accounting: subscriber
@@ -283,6 +284,7 @@ SCHEMA_KINDS = {
     "device.table_shards": "gauge",
     # engine.*
     "engine.completed": "counter",
+    "engine.decode_traces": "counter",
     "engine.demand_pager_gave_up": "counter",
     "engine.num_workers": "gauge",
     "engine.obs.subscriber_errors": "counter",

@@ -254,6 +254,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.events import EventBus, FenceIssued
 from repro.core.tracking import WORKER_OVERFLOW_BIT, worker_bit
 
@@ -615,12 +616,14 @@ class FenceEngine:
             workers=None if workers is None else tuple(int(w)
                                                        for w in workers),
             seq=self.seq, epoch=self.epoch, scoped=scoped)
-        if self.measure:
-            t0 = time.perf_counter()
-            self.bus.publish(evt)
-            self.stats.measured_s += time.perf_counter() - t0
-        else:
-            self.bus.publish(evt)
+        with tracing.span("fpr.fence", reason=reason, n_blocks=n_blocks,
+                          scoped=scoped, workers=evt.workers):
+            if self.measure:
+                t0 = time.perf_counter()
+                self.bus.publish(evt)
+                self.stats.measured_s += time.perf_counter() - t0
+            else:
+                self.bus.publish(evt)
 
     # -------------------------------------------------------------- accounting
     def note_skipped_free(self, n_blocks: int = 1) -> None:

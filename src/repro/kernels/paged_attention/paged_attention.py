@@ -59,6 +59,11 @@ walk innermost and sequential; online-softmax state (m, l, acc) lives in
 VMEM scratch across the walk.  Fully-invalid blocks (beyond the kv
 length or outside the sliding window) are skipped with ``pl.when``, so
 cost is proportional to the *resident* cache, not the table capacity.
+
+Each ``pallas_call`` is named after its entry point in ``ops.py``
+(``paged_attention``, ``paged_attention_split``,
+``ragged_paged_attention``): the name a TPU profile gives the kernel's
+operation, which the benchmark's roofline readers look up.
 """
 
 from __future__ import annotations
@@ -235,6 +240,7 @@ def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                              W=W, Bs=Bs, M=M)
     return pl.pallas_call(
         kern,
+        name="paged_attention_split",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         compiler_params=_compiler_params(),
@@ -310,6 +316,7 @@ def paged_attention_fused_fwd(q: jax.Array, kv_pool: jax.Array,
                              W=W, Bs=Bs, M=M)
     return pl.pallas_call(
         kern,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         compiler_params=_compiler_params(),
@@ -411,6 +418,7 @@ def paged_attention_fused_pipelined_fwd(
                              W=W, Bs=Bs, M=M, depth=buffer_depth)
     return pl.pallas_call(
         kern,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         compiler_params=_compiler_params(),
@@ -510,6 +518,7 @@ def ragged_fused_fwd(q: jax.Array, kv_pool: jax.Array,
                              W=W, Bs=Bs, M=M)
     return pl.pallas_call(
         kern,
+        name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=_compiler_params(),

@@ -35,12 +35,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.config import validate_translation, validate_worker_count
 from repro.core.eviction import WatermarkEvictor
-from repro.core.events import (FenceIssued, PrefillChunkDone,
-                               PreemptionResolved, PreemptionStarted,
-                               RequestCompleted, ShardRefreshed,
-                               StepCompleted)
+from repro.core.events import (FenceIssued, PreemptionResolved,
+                               PreemptionStarted, ShardRefreshed)
 from repro.models import transformer as tfm
 from repro.models.config import ModelConfig
 from repro.serving.admission import CapacityError, MemoryGovernor
@@ -145,6 +144,8 @@ class Engine:
         self.evictor = WatermarkEvictor(self.cache.mgr, self._lru_victims,
                                         watermarks=config.watermarks)
         self.metrics.register("fpr.eviction", self.evictor.counters)
+        # this engine's spans, steps and request records (core/tracing.py)
+        self.trace_id = tracing.RECORDER.new_engine()
         self.steps = 0
         self.tokens_generated = 0
         self.wall_s = 0.0
@@ -171,6 +172,7 @@ class Engine:
         # tests/test_chunked_prefill.py
         self._prefill_traces = 0
         self._prefill_chunk_traces = 0
+        self._decode_traces = 0
         if (config.prefix_sharing and config.fpr_enabled
                 and not self._chunked and jax.default_backend() == "tpu"):
             # monolithic prefill compiles one program per padded prompt
@@ -200,9 +202,11 @@ class Engine:
             seg = -(-self.chunk_tokens // QT) * QT
             self._t_cap = config.max_batch * seg
 
-        self._decode = jax.jit(
-            lambda p, st, t: tfm.decode_step(p, cfg, st, t,
-                                             page_impl=config.page_impl))
+        def _decode_traced(p, st, t):
+            self._decode_traces += 1
+            return tfm.decode_step(p, cfg, st, t, page_impl=config.page_impl)
+
+        self._decode = jax.jit(_decode_traced)
 
         def _ragged_traced(p, st, toks, token_row, token_pos, tile_row,
                            tile_pos, kv_lens, last_index):
@@ -228,14 +232,16 @@ class Engine:
     def submit(self, prompt, max_new_tokens: int, stream: str = "default",
                group_id: int = 1, priority: int = 0,
                sla: float | None = None) -> int:
+        t_submit = tracing.now_ns()
         # prompt-block chain hashes are computed exactly once, here — the
         # governor's probe and the allocation both reuse them
         rid = self.sched.submit(prompt, max_new_tokens, stream, group_id,
                                 priority, sla=sla,
                                 prefix_hashes=self.cache.prefix_hashes(
                                     prompt))
+        r = self.sched.queue[-1]
         # queue-wait clock zero: the engine step this submit landed on
-        self.sched.queue[-1].submit_step = self.steps
+        r.submit_step = self.steps
         if self.governor is not None:
             # fast-reject on the governor's own admissibility estimate, not
             # the raw prompt+budget window: a heavily shared long prompt
@@ -244,13 +250,14 @@ class Engine:
             # raw check wrongly refused prompts admissible_ever accepts
             # (and, under chunked admission, prompts the chunk machine can
             # serve within the limit)
-            r = self.sched.queue[-1]
             if not self.governor.admissible_ever(r):
                 self.sched.queue.pop()
                 raise CapacityError(
                     f"request window of {self.governor.window_blocks(r)} "
                     f"blocks can never fit the admission limit of "
                     f"{self.governor.ledger.limit}")
+        r.trace = tracing.RECORDER.request(self.trace_id, rid, len(r.prompt),
+                                           t_submit)
         return rid
 
     def _lru_victims(self):
@@ -349,7 +356,8 @@ class Engine:
         if self.governor is not None:
             self.governor.reshard(new_num_workers, translation,
                                   topology=topo)
-        plan = self.cache.reshape(topo, translation)
+        with tracing.acting(self.trace_id):
+            plan = self.cache.reshape(topo, translation)
         self.config = self.config.replace(
             num_workers=new_num_workers,
             islands=None if topo.is_flat else topo.spec)
@@ -416,6 +424,7 @@ class Engine:
                 self._reserve_settle(
                     r, lambda: self.governor.on_allocated(
                         r, m.num_blocks - m.prefix_hits))
+            r.trace.admitted(r.mapping.prefix_hits * self.cache.block_size)
             if not self._chunked:
                 self._prefill_request(r)
             # chunked requests stay in state "prefill": step() runs one
@@ -545,49 +554,57 @@ class Engine:
                 r, free=lambda m: self.cache.free_sequence(m, worker=worker))
         # the governor's preemption counters subscribe to this event
         self.bus.publish(PreemptionResolved(rid=r.rid, strategy=strategy))
+        r.trace.preempted()
+        tracing.mark("engine.preempt", rid=r.rid, strategy=strategy)
         # the re-queued victim's queue-wait clock restarts at preemption
         r.submit_step = self.steps
         return strategy
 
     def _prefill_request(self, r: Request) -> None:
         """Single-sequence prefill into the request's blocks."""
-        S = len(r.prompt)
-        bs = self.cache.block_size
-        Sp = max(bs, -(-S // bs) * bs)              # pad to block multiple
-        toks = np.zeros((1, Sp), np.int32)
-        toks[0, :S] = r.prompt
-        tables = self.cache.slot_tables({0: r.mapping})[:1]
-        # Attached prefix blocks already hold their owner's KV, and running
-        # sharers read it.  A prompt of another length recomputes the same
-        # prefix in different low bits on TPU, so rewriting them would
-        # change the context of requests mid-decode: drop those writes.
-        tables = tables.at[0, :r.mapping.prefix_hits].set(-1)
-        st = dict(self.cache.state)
-        st["tables"] = tables
-        st["lengths"] = jnp.zeros((1,), jnp.int32)
-        # batch-1 view of recurrent/cross states
-        view = {}
-        for k, v in st.items():
-            if k in ("tables", "lengths"):
-                view[k] = st[k]
-            elif k in _SLOT_STATE_KEYS:
-                view[k] = v[:, r.slot:r.slot + 1]
-            else:
-                view[k] = v
-        logits, new = self._prefill(self.params, jnp.asarray(toks), view)
-        for k, v in new.items():
-            if k in ("tables", "lengths"):
-                continue
-            if k in _SLOT_STATE_KEYS:
-                self.cache.state[k] = self.cache.state[k].at[
-                    :, r.slot:r.slot + 1].set(v)
-            else:
-                self.cache.state[k] = v
-        # first generated token comes from position S-1 (prefill is padded;
-        # recompute the true last-token logits on the next decode step if
-        # padding hid it — for simplicity prompts are block-aligned in
-        # benchmarks; otherwise we decode from the argmax here)
-        del logits
+        with tracing.span("engine.dispatch"):
+            S = len(r.prompt)
+            bs = self.cache.block_size
+            Sp = max(bs, -(-S // bs) * bs)          # pad to block multiple
+            toks = np.zeros((1, Sp), np.int32)
+            toks[0, :S] = r.prompt
+            tables = self.cache.slot_tables({0: r.mapping})[:1]
+            # Attached prefix blocks already hold their owner's KV, and
+            # running sharers read it.  A prompt of another length
+            # recomputes the same prefix in different low bits on TPU, so
+            # rewriting them would change the context of requests
+            # mid-decode: drop those writes.
+            tables = tables.at[0, :r.mapping.prefix_hits].set(-1)
+            st = dict(self.cache.state)
+            st["tables"] = tables
+            st["lengths"] = jnp.zeros((1,), jnp.int32)
+            # batch-1 view of recurrent/cross states
+            view = {}
+            for k, v in st.items():
+                if k in ("tables", "lengths"):
+                    view[k] = st[k]
+                elif k in _SLOT_STATE_KEYS:
+                    view[k] = v[:, r.slot:r.slot + 1]
+                else:
+                    view[k] = v
+            logits, new = self._prefill(self.params, jnp.asarray(toks),
+                                        view)
+            for k, v in new.items():
+                if k in ("tables", "lengths"):
+                    continue
+                if k in _SLOT_STATE_KEYS:
+                    self.cache.state[k] = self.cache.state[k].at[
+                        :, r.slot:r.slot + 1].set(v)
+                else:
+                    self.cache.state[k] = v
+            # first generated token comes from position S-1 (prefill is
+            # padded; recompute the true last-token logits on the next
+            # decode step if padding hid it — for simplicity prompts are
+            # block-aligned in benchmarks; otherwise we decode from the
+            # argmax here)
+            del logits
+        r.trace.prefilled(0, len(r.prompt),
+                          r.mapping.prefix_hits * self.cache.block_size)
 
     def _prefill_chunk_step(self, r: Request) -> None:
         """One fixed-shape prefill chunk for ``r`` — the chunked state
@@ -603,33 +620,34 @@ class Engine:
         seat a more urgent queued request first — bounded, never a
         livelock.
         """
-        if not self._grow_for_chunk(r):
-            return                    # policy deferred this step's growth
-        S = len(r.prompt)
-        start = r.prefill_pos
-        C = self.chunk_tokens
-        m = r.mapping
-        end = min(S, start + C)
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :end - start] = r.prompt[start:end]
-        view = {}
-        for k, v in self.cache.state.items():
-            if k == "tables":
-                view[k] = self.cache.slot_tables({0: m})[:1]
-            elif k == "lengths":
-                view[k] = jnp.zeros((1,), jnp.int32)
-            else:
-                view[k] = v
-        new = self._prefill_chunk(self.params, jnp.asarray(toks), view,
-                                  jnp.int32(start))
-        for k, v in new.items():
-            if k not in ("tables", "lengths"):
-                self.cache.state[k] = v
-        r.prefill_pos = end
-        self.prefill_chunks += 1
-        if self.bus.wants(PrefillChunkDone):
-            self.bus.publish(PrefillChunkDone(rid=r.rid, start=start,
-                                              end=end, step=self.steps))
+        with tracing.span("engine.grow"):
+            if not self._grow_for_chunk(r):
+                return                # policy deferred this step's growth
+        with tracing.span("engine.dispatch"):
+            S = len(r.prompt)
+            start = r.prefill_pos
+            C = self.chunk_tokens
+            m = r.mapping
+            end = min(S, start + C)
+            toks = np.zeros((1, C), np.int32)
+            toks[0, :end - start] = r.prompt[start:end]
+            view = {}
+            for k, v in self.cache.state.items():
+                if k == "tables":
+                    view[k] = self.cache.slot_tables({0: m})[:1]
+                elif k == "lengths":
+                    view[k] = jnp.zeros((1,), jnp.int32)
+                else:
+                    view[k] = v
+            new = self._prefill_chunk(self.params, jnp.asarray(toks),
+                                      view, jnp.int32(start))
+            for k, v in new.items():
+                if k not in ("tables", "lengths"):
+                    self.cache.state[k] = v
+            r.prefill_pos = end
+            self.prefill_chunks += 1
+        r.trace.prefilled(start, end,
+                          m.prefix_hits * self.cache.block_size)
         if r.prefill_pos >= S:
             r.state = "running"    # decodes this very step (interleaved)
 
@@ -768,12 +786,26 @@ class Engine:
 
     # ----------------------------------------------------------------- step
     def step(self) -> int:
-        """One engine iteration; returns tokens generated."""
+        """One engine iteration; returns tokens generated.
+
+        The whole step is one ``tracing.STEP_SPAN`` span, its phases
+        (``engine.admit`` … ``engine.emit``) spans inside it."""
+        with tracing.step(self.trace_id, self.steps, self._trace_counts):
+            return self._step()
+
+    def _trace_counts(self) -> tuple:
+        """Times each jitted program has been traced, for the step
+        record (the ragged program counts as ``prefill_chunk``)."""
+        return (("prefill", self._prefill_traces),
+                ("prefill_chunk", self._prefill_chunk_traces),
+                ("decode", self._decode_traces))
+
+    def _step(self) -> int:
         t0 = time.perf_counter()
-        self._admit()
+        with tracing.span("engine.admit"):
+            self._admit()
         if not self.sched.running:
             return 0
-        self.evictor.maybe_evict()
 
         # demand paging: fault back any swapped-out block the step will
         # read (the paper's page-cache read path; triggers swap-in +
@@ -788,7 +820,9 @@ class Engine:
         # give-up instead *preempts* victims until the pager converges
         # (raising CapacityError if no victim remains) — pressure becomes
         # preemption, never silent token divergence.
-        self._settle_residency()
+        with tracing.span("engine.residency"):
+            self.evictor.maybe_evict()
+            self._settle_residency()
         if not self.sched.running:
             return 0
 
@@ -813,13 +847,16 @@ class Engine:
                     self._prefill_chunk_step(r)
                     progressed = True
                 elif r.state == "running":
-                    progressed |= self._grow_for_decode(r)
+                    with tracing.span("engine.grow"):
+                        progressed |= self._grow_for_decode(r)
             if progressed:
-                self._settle_residency()
+                with tracing.span("engine.residency"):
+                    self._settle_residency()
                 if not self.sched.running:
                     return 0
 
-        self._cow_pass()
+        with tracing.span("engine.cow"):
+            self._cow_pass()
 
         # decode covers only fully-prefilled slots; a mid-prefill slot is
         # excluded from the tables upload (its row reads -1, so the decode
@@ -831,47 +868,37 @@ class Engine:
             # every occupied slot is still mid-prefill: the step did its
             # chunk work; decode resumes once a request promotes
             self.steps += 1
-            self._finish_step(t0, 0)
+            self._finish_step(t0)
             return 0
 
         # the incoming token is the last *known* token; it is (re)written at
         # its own position r.length−1 (idempotent for the prompt tail) and
         # the logits predict position r.length.
-        lengths = np.zeros((self.cache.max_batch,), np.int32)
-        tokens = np.zeros((self.cache.max_batch,), np.int32)
-        for slot, r in decoders.items():
-            lengths[slot] = r.length - 1
-            tokens[slot] = (r.generated[-1] if r.generated
-                            else r.prompt[-1])
-        self.cache.update_tables(
-            {s: r.mapping for s, r in decoders.items()}, lengths)
+        with tracing.span("engine.tables"):
+            lengths = np.zeros((self.cache.max_batch,), np.int32)
+            tokens = np.zeros((self.cache.max_batch,), np.int32)
+            for slot, r in decoders.items():
+                lengths[slot] = r.length - 1
+                tokens[slot] = (r.generated[-1] if r.generated
+                                else r.prompt[-1])
+            self.cache.update_tables(
+                {s: r.mapping for s, r in decoders.items()}, lengths)
 
-        st = dict(self.cache.state)
-        logits, new_state = self._decode(self.params, st,
-                                         jnp.asarray(tokens))
-        self.cache.state = new_state
-        lg = np.asarray(logits)
+        with tracing.span("engine.dispatch"):
+            logits, new_state = self._decode(self.params,
+                                             dict(self.cache.state),
+                                             jnp.asarray(tokens))
+            self.cache.state = new_state
+        with tracing.span("engine.fetch"):
+            lg = np.asarray(logits)
 
-        made = 0
-        for slot, r in list(decoders.items()):
-            nxt = int(lg[slot].argmax())
-            r.generated.append(nxt)
-            made += 1
-            if (len(r.generated) >= r.max_new_tokens
-                    or (self.eos is not None and nxt == self.eos)):
-                self.cache.free_sequence(r.mapping,
-                                         worker=self._worker_of(r))
-                r.mapping = None
-                if self.governor is not None:
-                    self.governor.on_release(r)
-                self.sched.complete(r)
-                if self.bus.wants(RequestCompleted):
-                    self.bus.publish(RequestCompleted(
-                        rid=r.rid, n_tokens=len(r.generated),
-                        step=self.steps))
+        with tracing.span("engine.emit"):
+            for slot, r in decoders.items():
+                self._emit(r, lg[slot])
+        made = len(decoders)
         self.steps += 1
         self.tokens_generated += made
-        self._finish_step(t0, made)
+        self._finish_step(t0)
         return made
 
     def _cow_pass(self) -> None:
@@ -912,125 +939,132 @@ class Engine:
         # the residency fixpoint, exactly like the per-slot chunk path
         chunkable: dict[int, Request] = {}
         progressed = False
-        for slot in sorted(self.sched.running):
-            r = self.sched.running.get(slot)
-            if r is None:
-                continue              # preempted by a mid-pass growth
-            if r.state == "prefill":
-                if self._grow_for_chunk(r):
-                    chunkable[slot] = r
-                    progressed = True
-            elif r.state == "running":
-                progressed |= self._grow_for_decode(r)
+        with tracing.span("engine.grow"):
+            for slot in sorted(self.sched.running):
+                r = self.sched.running.get(slot)
+                if r is None:
+                    continue          # preempted by a mid-pass growth
+                if r.state == "prefill":
+                    if self._grow_for_chunk(r):
+                        chunkable[slot] = r
+                        progressed = True
+                elif r.state == "running":
+                    progressed |= self._grow_for_decode(r)
         if progressed:
-            self._settle_residency()
+            with tracing.span("engine.residency"):
+                self._settle_residency()
             if not self.sched.running:
                 return 0
-        self._cow_pass()
+        with tracing.span("engine.cow"):
+            self._cow_pass()
 
-        rows = []                     # (slot, request, start, end)
-        for slot in sorted(self.sched.running):
-            r = self.sched.running[slot]
-            if r.state == "prefill":
-                if chunkable.get(slot) is not r:
-                    continue          # growth deferred this step
-                start = r.prefill_pos
-                end = min(len(r.prompt), start + self.chunk_tokens)
-            elif r.state == "running":
-                start, end = r.length - 1, r.length
-            else:
-                continue
-            rows.append((slot, r, start, end))
+        with tracing.span("engine.tables"):
+            rows = []                 # (slot, request, start, end)
+            for slot in sorted(self.sched.running):
+                r = self.sched.running[slot]
+                if r.state == "prefill":
+                    if chunkable.get(slot) is not r:
+                        continue      # growth deferred this step
+                    start = r.prefill_pos
+                    end = min(len(r.prompt), start + self.chunk_tokens)
+                elif r.state == "running":
+                    start, end = r.length - 1, r.length
+                else:
+                    continue
+                rows.append((slot, r, start, end))
+            if rows:
+                # tables upload covers every row's slot — chunk rows
+                # included, since their scatters and page walks go through
+                # the same kernel
+                lengths = np.zeros((self.cache.max_batch,), np.int32)
+                for slot, r, start, end in rows:
+                    lengths[slot] = start
+                self.cache.update_tables(
+                    {slot: r.mapping for slot, r, _, _ in rows}, lengths)
         if not rows:
             self.steps += 1
-            self._finish_step(t0, 0)
+            self._finish_step(t0)
             return 0
 
-        # tables upload covers every row's slot — chunk rows included,
-        # since their scatters and page walks go through the same kernel
-        lengths = np.zeros((self.cache.max_batch,), np.int32)
-        for slot, r, start, end in rows:
-            lengths[slot] = start
-        self.cache.update_tables(
-            {slot: r.mapping for slot, r, _, _ in rows}, lengths)
+        with tracing.span("engine.descriptor"):
+            d = build_ragged_descriptor(
+                [slot for slot, *_ in rows],
+                [end - start for _, _, start, end in rows],
+                [start for _, _, start, _ in rows],
+                [end for *_, end in rows],
+                num_slots=self.cache.max_batch, t_cap=self._t_cap)
+            flat = np.concatenate([
+                np.asarray(r.prompt[start:end], np.int32)
+                if r.state == "prefill"
+                else np.asarray([r.generated[-1] if r.generated
+                                 else r.prompt[-1]], np.int32)
+                for slot, r, start, end in rows])
+            toks = np.zeros((self._t_cap,), np.int32)
+            real = d["token_src"] >= 0
+            toks[real] = flat[d["token_src"][real]]
 
-        d = build_ragged_descriptor(
-            [slot for slot, *_ in rows],
-            [end - start for _, _, start, end in rows],
-            [start for _, _, start, _ in rows],
-            [end for *_, end in rows],
-            num_slots=self.cache.max_batch, t_cap=self._t_cap)
-        flat = np.concatenate([
-            np.asarray(r.prompt[start:end], np.int32)
-            if r.state == "prefill"
-            else np.asarray([r.generated[-1] if r.generated
-                             else r.prompt[-1]], np.int32)
-            for slot, r, start, end in rows])
-        toks = np.zeros((self._t_cap,), np.int32)
-        real = d["token_src"] >= 0
-        toks[real] = flat[d["token_src"][real]]
+        with tracing.span("engine.dispatch"):
+            logits, new_state = self._ragged_call(
+                self.params, dict(self.cache.state), jnp.asarray(toks),
+                jnp.asarray(d["token_row"]), jnp.asarray(d["token_pos"]),
+                jnp.asarray(d["tile_row"]), jnp.asarray(d["tile_pos"]),
+                jnp.asarray(d["kv_lens"]), jnp.asarray(d["last_index"]))
+            self.cache.state = new_state
+        with tracing.span("engine.fetch"):
+            lg = np.asarray(logits)
 
-        logits, new_state = self._ragged_call(
-            self.params, dict(self.cache.state), jnp.asarray(toks),
-            jnp.asarray(d["token_row"]), jnp.asarray(d["token_pos"]),
-            jnp.asarray(d["tile_row"]), jnp.asarray(d["tile_pos"]),
-            jnp.asarray(d["kv_lens"]), jnp.asarray(d["last_index"]))
-        self.cache.state = new_state
-        lg = np.asarray(logits)
+        with tracing.span("engine.emit"):
+            # host-side kernel accounting: one fused descriptor per
+            # resident block per row per attention layer (the split layout
+            # would pay two — see kernels/paged_attention/autotune.
+            # KernelCostModel)
+            kvp = self.cache.state["kv"]
+            block_bytes = int(np.prod(kvp.shape[2:])) * kvp.dtype.itemsize
+            bs = self.cache.block_size
+            n_layers = int(kvp.shape[0])
+            self._ragged_steps += 1
+            self._kernel_calls += n_layers
+            self._kernel_dma_bytes += n_layers * block_bytes * sum(
+                -(-end // bs) for *_, end in rows)
 
-        # host-side kernel accounting: one fused descriptor per resident
-        # block per row per attention layer (the split layout would pay
-        # two — see kernels/paged_attention/autotune.KernelCostModel)
-        kvp = self.cache.state["kv"]
-        block_bytes = int(np.prod(kvp.shape[2:])) * kvp.dtype.itemsize
-        bs = self.cache.block_size
-        n_layers = int(kvp.shape[0])
-        self._ragged_steps += 1
-        self._kernel_calls += n_layers
-        self._kernel_dma_bytes += n_layers * block_bytes * sum(
-            -(-end // bs) for *_, end in rows)
-
-        made = 0
-        for slot, r, start, end in rows:
-            if r.state == "prefill":
-                r.prefill_pos = end
-                self.prefill_chunks += 1
-                if self.bus.wants(PrefillChunkDone):
-                    self.bus.publish(PrefillChunkDone(
-                        rid=r.rid, start=start, end=end, step=self.steps))
-                if end < len(r.prompt):
-                    continue          # mid-prompt: no token this step
-                r.state = "running"
-            nxt = int(lg[slot].argmax())
-            r.generated.append(nxt)
-            made += 1
-            if (len(r.generated) >= r.max_new_tokens
-                    or (self.eos is not None and nxt == self.eos)):
-                self.cache.free_sequence(r.mapping,
-                                         worker=self._worker_of(r))
-                r.mapping = None
-                if self.governor is not None:
-                    self.governor.on_release(r)
-                self.sched.complete(r)
-                if self.bus.wants(RequestCompleted):
-                    self.bus.publish(RequestCompleted(
-                        rid=r.rid, n_tokens=len(r.generated),
-                        step=self.steps))
+            made = 0
+            for slot, r, start, end in rows:
+                if r.state == "prefill":
+                    r.prefill_pos = end
+                    self.prefill_chunks += 1
+                    r.trace.prefilled(start, end,
+                                      r.mapping.prefix_hits * bs)
+                    if end < len(r.prompt):
+                        continue      # mid-prompt: no token this step
+                    r.state = "running"
+                self._emit(r, lg[slot])
+                made += 1
         self.steps += 1
         self.tokens_generated += made
-        self._finish_step(t0, made)
+        self._finish_step(t0)
         return made
 
-    def _finish_step(self, t0: float, made: int) -> None:
-        """Step epilogue: wall-time accounting, the step-latency
-        histogram, and the :class:`StepCompleted` span event."""
+    def _emit(self, r: Request, logits_row: np.ndarray) -> None:
+        """Append ``r``'s next token (greedy); complete and free it once
+        its budget or EOS is reached."""
+        nxt = int(logits_row.argmax())
+        r.generated.append(nxt)
+        r.trace.token()
+        if (len(r.generated) >= r.max_new_tokens
+                or (self.eos is not None and nxt == self.eos)):
+            self.cache.free_sequence(r.mapping, worker=self._worker_of(r))
+            r.mapping = None
+            if self.governor is not None:
+                self.governor.on_release(r)
+            self.sched.complete(r)
+            r.trace.completed()
+
+    def _finish_step(self, t0: float) -> None:
+        """Step epilogue: wall-time accounting and the step-latency
+        histogram."""
         dt = time.perf_counter() - t0
         self.wall_s += dt
         self._hist_step.observe(dt, exemplar=f"step-{self.steps}")
-        if self.bus.wants(StepCompleted):
-            self.bus.publish(StepCompleted(step=self.steps, tokens=made,
-                                           wall_s=dt,
-                                           running=len(self.sched.running)))
 
     def run(self, max_steps: int = 10_000) -> dict:
         while not self.sched.idle and self.steps < max_steps:
@@ -1074,4 +1108,5 @@ class Engine:
             "prefill_chunks": self.prefill_chunks,
             "prefill_traces": self._prefill_traces,
             "prefill_chunk_traces": self._prefill_chunk_traces,
+            "decode_traces": self._decode_traces,
         }

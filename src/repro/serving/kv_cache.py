@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.block_table import Mapping
 from repro.core.config import FprConfig
 from repro.core.contexts import ContextRegistry, ContextScope
@@ -312,11 +313,14 @@ class PagedKVCache:
             self._full_refreshes += 1
         else:
             self._shard_refreshes += 1
+        shards = tuple(int(s) for s in shards)
+        tracing.mark("fpr.shard_refresh", reason=reason, shards=shards,
+                     entries=entries + d_entries, nbytes=nbytes + d_bytes,
+                     full=workers is None)
         if self.bus.wants(ShardRefreshed):
             self.bus.publish(ShardRefreshed(
-                reason=reason, shards=tuple(int(s) for s in shards),
-                entries=entries + d_entries, nbytes=nbytes + d_bytes,
-                full=workers is None))
+                reason=reason, shards=shards, entries=entries + d_entries,
+                nbytes=nbytes + d_bytes, full=workers is None))
 
     # ------------------------------------------------------------- reshard
     @property

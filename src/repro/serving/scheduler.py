@@ -64,6 +64,7 @@ class Request:
     submit_step: int = 0               # engine step at (re-)enqueue — the
                                        # queue-wait histogram's clock zero
                                        # (reset on preemption re-queue)
+    trace: Optional[object] = None     # its core.tracing.RequestRecord
 
     @property
     def length(self) -> int:

@@ -445,10 +445,10 @@ def kernel_case(smoke: bool = False) -> dict:
 
     Decoded tokens must be **bit-identical** across all three (the
     ragged pack only changes *which call* serves a row, never what its
-    attention computes), every ragged engine must hold the one-trace
-    contract (``prefill_chunk_traces == 1``), and the kernel counters
-    must show exactly one ragged kernel call per attention layer per
-    step — ``kernel_calls == n_layers * ragged_steps`` — whatever the
+    attention computes), every ragged engine must trace one program per
+    token capacity (``prefill_chunk_traces == programs``), and the kernel
+    counters must show exactly one ragged kernel call per attention layer
+    per step — ``kernel_calls == n_layers * ragged_steps`` — whatever the
     step's prefill/decode blend.  The tuned-vs-naive delta is modeled
     (``KernelCostModel``, like ``FenceCostModel``): interpret-mode wall
     clocks on CPU are noise.
@@ -489,6 +489,7 @@ def kernel_case(smoke: bool = False) -> dict:
                       for r in sorted(eng.sched.done, key=lambda r: r.rid)]
         snap = eng.metrics.snapshot()
         out[mode] = {k: snap.get(k) for k in _KERNEL_KEYS}
+        out[mode]["programs"] = len(eng.ragged_capacities)
     out["tokens_identical"] = (toks["chunked_ref"] == toks["ragged_ref"]
                                == toks["ragged_kernel"])
     # fixed-seed fingerprint of the decoded stream — a run-to-run drift
@@ -537,11 +538,11 @@ def kernel_report(out: dict) -> None:
             "ragged serving changed decoded tokens vs the chunked oracle")
     for mode in ("ragged_ref", "ragged_kernel"):
         m = out[mode]
-        if (m["engine.prefill_chunk_traces"] != 1
+        if (m["engine.prefill_chunk_traces"] != m["programs"]
                 or m["engine.prefill_traces"]):
             raise AssertionError(
-                f"{mode} must trace exactly once (got "
-                f"{m['engine.prefill_chunk_traces']} chunk traces, "
+                f"{mode} must trace once per capacity, {m['programs']} "
+                f"(got {m['engine.prefill_chunk_traces']} chunk traces, "
                 f"{m['engine.prefill_traces']} monolithic traces)")
         calls, steps = (m["engine.kernel.kernel_calls"],
                         m["engine.kernel.ragged_steps"])

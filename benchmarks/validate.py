@@ -23,12 +23,13 @@ across prompt lengths, and the mice-and-elephants ``queue_wait_p99``
 strictly better chunked than monolithic — ``BENCH_kernel.json`` (ragged
 fused-KV serving) must keep tokens bit-identical to the chunked oracle,
 exactly one ragged kernel call per attention layer per step under mixed
-prefill+decode batches, a single compile, and the autotuned fused
-pipeline at or below the naive split walk — and ``BENCH_load.json`` (the
-open-loop load harness) must carry every workload with a present
-queue-wait/step-latency p99, finite fences/token and refreshed
-bytes/token, tokens bit-identical to the fixed-seed replay, and a trace
-summary with at least one root span and zero left-open spans.  The
+prefill+decode batches, one compile per token capacity, and the
+autotuned fused pipeline at or below the naive split walk — and
+``BENCH_load.json`` (the open-loop load harness) must carry every
+workload with a present queue-wait/step-latency p99, finite
+fences/token and refreshed bytes/token, tokens bit-identical to the
+fixed-seed replay, and a trace summary with at least one root span and
+zero left-open spans.  The
 ``BENCH_topology.json`` (hierarchical islands) must keep the
 multi-island replay token-identical to flat scoped fencing, the strict
 cross-island device-bytes win, both fence levels exercised, and
@@ -251,10 +252,11 @@ def kernel_violations(path: str) -> list[str]:
             bad.append(f"{mode}: {calls} kernel calls over {steps} steps "
                        f"— a mixed batch must cost one call per layer "
                        f"per step ({n_layers} layer(s))")
-        if m.get("engine.prefill_chunk_traces") != 1:
+        if m.get("engine.prefill_chunk_traces") != m.get("programs"):
             bad.append(f"{mode}: ragged step traced "
                        f"{m.get('engine.prefill_chunk_traces')} times "
-                       f"(fixed descriptor shapes must compile once)")
+                       f"(one program per token capacity: "
+                       f"{m.get('programs')})")
     md = payload.get("modeled") or {}
     tuned, naive = md.get("tuned_fused_s"), md.get("naive_split_s")
     if tuned is None or naive is None or tuned > naive:

@@ -19,10 +19,13 @@ so its bf16 weights fit one 16 GB chip; weights are random, made from
   so a sharer would read other bits than it computes alone.
 * **Phase B** — the ragged path (``chunked_prefill=True,
   ragged_kernel=True``): one ragged kernel call per attention layer per
-  step, one trace of the mixed step for every prompt length.  Run with
-  FPR and prefix sharing on, and with FPR off; the tokens must be
-  identical (every prompt runs through the same fixed-shape program, so
-  a shared prefix has the same bits whoever computed it).
+  step, each step in the smallest of the engine's token capacities that
+  holds its rows, one program per capacity compiled with the engine.
+  Run with FPR and prefix sharing on, and with FPR off; the tokens must
+  be identical: a shared prefix computed in one capacity's program is
+  read by sharers whose own rows run in another, so the programs must
+  give a prefix the same bits whichever computed it.  The steps each
+  capacity served are printed.
 * **Reference** — on identical state, one decode step and one ragged
   step through the Pallas kernels must give the logits of the jnp
   reference (``page_impl="ref"``) within :data:`LOGIT_REL_TOL`, and the
@@ -141,6 +144,19 @@ def counters(snapshot: dict) -> dict:
     return {k: snapshot[k] for k in keep}
 
 
+def capacity_steps(eng) -> dict:
+    """Ragged steps of ``eng`` by the token capacity each ran in."""
+    from repro.core import tracing
+
+    out = {t: 0 for t in eng.ragged_capacities}
+    for st in tracing.RECORDER.steps:
+        if st.engine == eng.trace_id:
+            for p in st.phases or ():
+                if p.name == "engine.dispatch":
+                    out[p.args["tokens"]] += 1
+    return out
+
+
 def synthetic_state(state: dict, rng) -> dict:
     """The engine's cache with every slot's table filled from a random
     permutation of the pool — each slot then reads blocks that real
@@ -212,7 +228,7 @@ def reference_check(eng, cfg, clock: CompileClock, seed: int,
                     depth: int) -> dict:
     """One decode step and one ragged step, Pallas vs the jnp reference,
     on identical state.  Returns the measured errors."""
-    from repro.kernels.paged_attention.ops import QT, build_ragged_descriptor
+    from repro.kernels.paged_attention.ops import build_ragged_descriptor
     from repro.models import transformer as tfm
 
     rng = np.random.RandomState(seed + 1)
@@ -265,14 +281,9 @@ def reference_check(eng, cfg, clock: CompileClock, seed: int,
     out["ragged_rel_err"] = rel_err(*logits)
     out["ragged_argmax_agree"] = float(np.mean(
         logits[0].argmax(-1) == logits[1].argmax(-1)))
-    # the engine's own ragged step carries the kernel too
-    t_cap = eng._t_cap
-    eng_args = [jnp.zeros((t_cap,), jnp.int32)] * 3 + [
-        jnp.zeros((t_cap // QT,), jnp.int32)] * 2 + [
-        jnp.ones((B,), jnp.int32), jnp.full((B,), -1, jnp.int32)]
-    check_kernel(eng._ragged_call.lower(eng.params, dict(eng.cache.state),
-                                        *eng_args).compile(),
-                 "engine ragged step")
+    # the engine's own ragged programs carry the kernel too
+    for (t_cap, _), compiled in eng._ragged_programs.items():
+        check_kernel(compiled, f"engine ragged step T={t_cap}")
     # -- one layer's attention, kernel vs a full-precision reference
     out.update(attention_check(st, d, cfg, depth, rng))
     out["compile_s"] = round(clock.total - c0, 3)
@@ -378,10 +389,13 @@ def main() -> int:
               f"kernel_calls={snap['engine.kernel.kernel_calls']} "
               f"ragged_steps={snap['engine.kernel.ragged_steps']} "
               f"{counters(snap)}")
-        if snap["engine.prefill_chunk_traces"] != 1:
+        print(f"phase B (ragged, fpr={'on' if fpr else 'off'}): steps by "
+              f"capacity {capacity_steps(eng)}")
+        if snap["engine.prefill_chunk_traces"] != len(eng.ragged_capacities):
             raise AssertionError(
                 "phase B: the ragged step traced "
-                f"{snap['engine.prefill_chunk_traces']} times")
+                f"{snap['engine.prefill_chunk_traces']} times for "
+                f"{len(eng.ragged_capacities)} capacities")
         if (snap["engine.kernel.kernel_calls"]
                 != cfg.n_layers * snap["engine.kernel.ragged_steps"]):
             raise AssertionError("phase B: not one kernel call per layer "

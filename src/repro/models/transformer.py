@@ -939,10 +939,10 @@ def ragged_step(params, cfg: ModelConfig, state: dict, tokens: jax.Array,
     kv_lens: (num_slots,) kv length of each slot *after* this step's
     writes land; last_index: (num_slots,) packed index of each slot's
     final real token (-1 = inactive slot — its logits row is garbage and
-    its length is left untouched).  All shapes are static, so the whole
-    mixed step — any blend of prefill chunks and single-token decodes —
-    compiles exactly once.  Returns (logits (num_slots, V) gathered at
-    each slot's last token, new state).
+    its length is left untouched).  Every shape but ``T`` is fixed by
+    the cache, so one program per ``T`` serves any blend of prefill
+    chunks and single-token decodes that fits it.  Returns (logits
+    (num_slots, V) gathered at each slot's last token, new state).
 
     Per layer the incoming fused K/V rows are scattered *before* the
     attention call (so a chunk attends its own tokens, matching

@@ -53,6 +53,27 @@ from repro.serving.scheduler import Request, Scheduler
 _SLOT_STATE_KEYS = ("conv", "ssm", "rwkv_x", "rwkv_s", "cross_k", "cross_v")
 
 
+def ragged_capacities(max_batch: int, chunk_tokens: int) -> tuple:
+    """Token capacities of the ragged step's programs, smallest first.
+
+    A chunk row pads to ``seg`` (``chunk_tokens`` rounded up to the
+    kernel's query tile ``QT``) and a decode row to ``QT``.  For k = 0, 1,
+    2 chunk rows the capacity is ``k·seg + max_batch·QT`` (a decode row in
+    every slot beside them), capped at ``max_batch·seg``, which holds a
+    chunk in every slot and is always a capacity.
+    """
+    from repro.kernels.paged_attention.ops import QT
+    seg = -(-chunk_tokens // QT) * QT
+    t_cap = max_batch * seg
+    return tuple(sorted({min(t_cap, k * seg + max_batch * QT)
+                         for k in range(3)} | {t_cap}))
+
+
+def pick_capacity(capacities: tuple, packed: int) -> int:
+    """The smallest of ``capacities`` that holds ``packed`` token rows."""
+    return next(c for c in capacities if c >= packed)
+
+
 class Engine:
     """Continuous-batching engine over the FPR paged cache.
 
@@ -188,19 +209,21 @@ class Engine:
             self.governor.chunk_blocks = config.prefill_chunk
 
         # Ragged fused-KV serving: every slot's incoming tokens — prefill
-        # chunks and decode rows alike — pack into ONE fixed-shape token
-        # stream and one ragged kernel call per layer per step.  The
-        # token capacity is static (max_batch rows, each padded to the
-        # kernel's query-tile multiple), so the whole mixed step keeps
-        # the chunk path's one-trace contract (_prefill_chunk_traces).
+        # chunks and decode rows alike — pack into ONE token stream and
+        # one ragged kernel call per layer per step.  The stream's length
+        # is one of a few static capacities (ragged_capacities), the
+        # smallest that holds the step's packed rows; each capacity is
+        # one program, all compiled here, before the first step, so
+        # _prefill_chunk_traces ends construction at len(capacities) and
+        # no step traces or compiles (but for the first steps after a
+        # reshard, which compile for the new tables' shape).
         self._ragged = config.ragged_kernel and self._chunked
         self._kernel_calls = 0
         self._ragged_steps = 0
         self._kernel_dma_bytes = 0
-        if self._ragged:
-            from repro.kernels.paged_attention.ops import QT
-            seg = -(-self.chunk_tokens // QT) * QT
-            self._t_cap = config.max_batch * seg
+        self.ragged_capacities = (
+            ragged_capacities(config.max_batch, self.chunk_tokens)
+            if self._ragged else ())
 
         def _decode_traced(p, st, t):
             self._decode_traces += 1
@@ -215,7 +238,26 @@ class Engine:
                                    tile_row, tile_pos, kv_lens, last_index,
                                    page_impl=config.page_impl)
 
-        self._ragged_call = jax.jit(_ragged_traced)
+        ragged_jit = jax.jit(_ragged_traced)
+        #: compiled ragged programs by (capacity, tables shape)
+        self._ragged_programs = {}
+
+        def _ragged_program(p, st, toks, *desc):
+            key = (toks.shape[0], st["tables"].shape)
+            if key not in self._ragged_programs:   # construction, reshape
+                self._ragged_programs[key] = ragged_jit.lower(
+                    p, st, toks, *desc).compile()
+            return self._ragged_programs[key]
+
+        self._ragged_call = lambda *a: _ragged_program(*a)(*a)
+        if self._ragged:
+            from repro.kernels.paged_attention.ops import QT
+            B, i32 = config.max_batch, jnp.int32
+            for t in self.ragged_capacities:
+                _ragged_program(params, dict(self.cache.state),
+                                *[jax.ShapeDtypeStruct((t,), i32)] * 3,
+                                *[jax.ShapeDtypeStruct((t // QT,), i32)] * 2,
+                                *[jax.ShapeDtypeStruct((B,), i32)] * 2)
 
         def _prefill_traced(p, t, st):
             self._prefill_traces += 1
@@ -925,15 +967,15 @@ class Engine:
     def _ragged_pass(self, t0: float) -> int:
         """One ragged engine iteration: every slot's incoming tokens —
         prefill chunks and single-token decode rows alike — pack into one
-        fixed-shape stream served by ONE ragged fused-KV kernel call per
-        attention layer.  A request whose last chunk lands this step
-        promotes in place: its chunk's last-token logits *are* the first
-        decode logits (same position, same attended prefix), so it emits
-        a token this very step, exactly like the per-slot chunk path.
-        All descriptor shapes are static (``max_batch`` rows padded to
-        the kernel's query-tile multiple), so the whole mixed step keeps
-        the one-trace contract the chunk path pins."""
-        from repro.kernels.paged_attention.ops import build_ragged_descriptor
+        stream served by ONE ragged fused-KV kernel call per attention
+        layer.  A request whose last chunk lands this step promotes in
+        place: its chunk's last-token logits *are* the first decode logits
+        (same position, same attended prefix), so it emits a token this
+        very step, exactly like the per-slot chunk path.  The stream's
+        length is the smallest of :attr:`ragged_capacities` that holds the
+        packed rows, whose program was compiled with the engine."""
+        from repro.kernels.paged_attention.ops import (
+            QT, build_ragged_descriptor)
 
         # growth (chunk reservations + decode write blocks), then restore
         # the residency fixpoint, exactly like the per-slot chunk path
@@ -987,23 +1029,26 @@ class Engine:
             return 0
 
         with tracing.span("engine.descriptor"):
+            q_lens = [end - start for _, _, start, end in rows]
+            t_cap = pick_capacity(self.ragged_capacities,
+                                  sum(-(-n // QT) * QT for n in q_lens))
             d = build_ragged_descriptor(
-                [slot for slot, *_ in rows],
-                [end - start for _, _, start, end in rows],
+                [slot for slot, *_ in rows], q_lens,
                 [start for _, _, start, _ in rows],
                 [end for *_, end in rows],
-                num_slots=self.cache.max_batch, t_cap=self._t_cap)
+                num_slots=self.cache.max_batch, t_cap=t_cap)
             flat = np.concatenate([
                 np.asarray(r.prompt[start:end], np.int32)
                 if r.state == "prefill"
                 else np.asarray([r.generated[-1] if r.generated
                                  else r.prompt[-1]], np.int32)
                 for slot, r, start, end in rows])
-            toks = np.zeros((self._t_cap,), np.int32)
+            toks = np.zeros((t_cap,), np.int32)
             real = d["token_src"] >= 0
             toks[real] = flat[d["token_src"][real]]
 
-        with tracing.span("engine.dispatch"):
+        with tracing.span("engine.dispatch", tokens=t_cap,
+                          real=sum(q_lens)):
             logits, new_state = self._ragged_call(
                 self.params, dict(self.cache.state), jnp.asarray(toks),
                 jnp.asarray(d["token_row"]), jnp.asarray(d["token_pos"]),

@@ -208,10 +208,10 @@ def _drive_elastic_trace(trace, workers, *, scoped, check_soundness):
     freed: dict[int, tuple] = {}    # block → (ctx, version, holders@free)
     reads: list = []
 
-    def check_reuse(m, c):
-        for b in m.physical:
+    def check_reuse(blocks, ctx_id):
+        for b in blocks:
             fctx, fver, fholders = freed.pop(b, (None, None, set()))
-            if fctx is not None and fctx != c.ctx_id:
+            if fctx is not None and fctx != ctx_id:
                 topo = eng.topology
                 for hw in fholders:
                     assert int(eng.worker_epochs[hw]) > fver, (
@@ -265,7 +265,7 @@ def _drive_elastic_trace(trace, workers, *, scoped, check_soundness):
                 reads.append(("oom",))
                 continue
             if check_soundness:
-                check_reuse(m, c)
+                check_reuse(m.physical, c.ctx_id)
                 for b in m.physical:
                     holders.setdefault(b, set()).add(w)
             live.append(m)
@@ -288,6 +288,10 @@ def _drive_elastic_trace(trace, workers, *, scoped, check_soundness):
             idx = size % m.num_blocks
             b, faulted = mgr.touch(m.mapping_id, idx, worker=w)
             if check_soundness:
+                if faulted:
+                    # a fault re-allocates: a foreign context's freed
+                    # block must be covered exactly as at map
+                    check_reuse([b], m.ctx_id)
                 holders.setdefault(b, set()).add(w)
             reads.append(("touch", b, faulted))
         elif op == "evict":

@@ -285,9 +285,14 @@ class TestEngineRecords:
         snap = s["eng"].metrics.snapshot()
         want = {"monolithic": {"prefill", "decode"},
                 "chunked": {"prefill_chunk", "decode"},
-                "ragged": {"prefill_chunk"}}[path]
+                "ragged": set()}[path]
         assert set(retraced) == want
-        assert s["steps"][0].retraced            # the first step compiles
+        if path == "ragged":
+            # each capacity's program is traced with the engine, no step
+            assert snap["engine.prefill_chunk_traces"] == len(
+                s["eng"].ragged_capacities)
+        else:
+            assert s["steps"][0].retraced        # the first step compiles
         # each step names a program whose trace counter rose in it
         for name in want:
             assert 1 <= retraced.count(name) <= snap[f"engine.{name}_traces"]

@@ -48,6 +48,7 @@ import numpy as np  # noqa: E402
 import check  # noqa: E402
 import counts  # noqa: E402
 import drive  # noqa: E402
+import host  # noqa: E402
 import trace_reduce  # noqa: E402
 
 #: the traced part of a ``--trace 1`` window: from this share of the
@@ -140,34 +141,84 @@ def configure_cache() -> str:
     return path
 
 
-#: configuration-file key → the program's ModelConfig field
+#: configuration-file key → where the program's ModelConfig holds it
+#: (``sub.field`` for its attention, MLA and expert configs); the one
+#: table both checks a file against the program and builds the smoke
+#: config from it
 _MODEL_KEYS = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
                "num_key_value_heads": "n_kv_heads",
                "intermediate_size": "d_ff", "vocab_size": "vocab",
                "head_dim": "head_dim", "num_hidden_layers": "n_layers",
                "rms_norm_eps": "norm_eps",
-               "tie_word_embeddings": "tie_embeddings"}
+               "tie_word_embeddings": "tie_embeddings",
+               "rope_theta": "attn.rope_theta",
+               "kv_lora_rank": "mla.kv_lora_rank",
+               "q_lora_rank": "mla.q_lora_rank",
+               "qk_rope_head_dim": "mla.rope_head_dim",
+               "qk_nope_head_dim": "mla.nope_head_dim",
+               "v_head_dim": "mla.v_head_dim",
+               "n_routed_experts": "moe.num_experts",
+               "num_experts_per_tok": "moe.top_k",
+               "n_shared_experts": "moe.num_shared",
+               "moe_intermediate_size": "moe.d_expert"}
+
+
+def _fields(cfg: dict) -> dict:
+    """The file's keys that the program holds, by where it holds them:
+    ``intermediate_size`` is the dense layers' ``dense_d_ff`` where the
+    model has expert layers, and a null ``q_lora_rank`` (no query
+    compression) is None."""
+    moe = any(f == "moe" for _, f in counts.layer_kinds(cfg))
+    out = {}
+    for k, f in _MODEL_KEYS.items():
+        if k in cfg:
+            if k == "intermediate_size" and moe:
+                f = "dense_d_ff"
+            out[f] = (cfg[k] or None) if k == "q_lora_rank" else cfg[k]
+    return out
+
+
+def _get(mc, path: str):
+    obj = mc
+    for part in path.split("."):
+        obj = getattr(obj, part, None) if obj is not None else None
+    return (obj or None) if path == "mla.q_lora_rank" else obj
 
 
 def program_config(cfg: dict, smoke: bool):
     """The program's ModelConfig for this configuration file; a file that
-    disagrees with the program's own config is an error."""
+    disagrees with the program's own config is an error that names the
+    file's key."""
     from dataclasses import replace
 
     from repro.configs import get_config
+    from repro.models.config import MLAConfig, MoEConfig
 
     mc = get_config(cfg["arch"], n_layers=cfg["num_hidden_layers"])
+    kinds = counts.layer_kinds(cfg)
+    mixers, ffns = tuple(m for m, _ in kinds), tuple(f for _, f in kinds)
+    fields = _fields(cfg)
     if smoke:
-        fields = {f: cfg[k] for k, f in _MODEL_KEYS.items()}
-        fields["mixers"] = ("attn",) * cfg["num_hidden_layers"]
-        fields["ffns"] = ("dense",) * cfg["num_hidden_layers"]
-        return replace(mc, **fields)
-    wrong = {k: (cfg[k], getattr(mc, f)) for k, f in _MODEL_KEYS.items()
-             if cfg[k] != getattr(mc, f)}
-    if cfg["rope_theta"] != mc.attn.rope_theta:
-        wrong["rope_theta"] = (cfg["rope_theta"], mc.attn.rope_theta)
-    if set(mc.mixers) != {"attn"} or set(mc.ffns) != {"dense"}:
-        wrong["layers"] = (mc.mixers, mc.ffns)
+        top = {f: v for f, v in fields.items() if "." not in f}
+        subs = {"attn": mc.attn, "mla": mc.mla or MLAConfig(),
+                "moe": mc.moe or MoEConfig(num_experts=0, top_k=0)}
+        for f, v in fields.items():
+            if "." in f:
+                sub, name = f.split(".")
+                subs[sub] = replace(subs[sub], **{name: v})
+        top["attn"] = subs["attn"]
+        top["mla"] = subs["mla"] if "mla" in mixers else None
+        top["moe"] = subs["moe"] if "moe" in ffns else None
+        return replace(mc, **top, mixers=mixers, ffns=ffns)
+    keys = {f: k for k, f in _MODEL_KEYS.items()}
+    keys["dense_d_ff"] = "intermediate_size"
+    wrong = {keys[f]: (v, _get(mc, f)) for f, v in fields.items()
+             if v != _get(mc, f)}
+    if mixers != mc.mixers:
+        wrong["kv_lora_rank (mixers)"] = (mixers, mc.mixers)
+    if ffns != mc.ffns:
+        wrong["n_routed_experts, first_k_dense_replace, moe_layer_freq "
+              "(ffns)"] = (ffns, mc.ffns)
     if wrong:
         raise SystemExit(f"configuration file disagrees with the program's "
                          f"{cfg['arch']}: {wrong}")
@@ -258,6 +309,7 @@ def run(args, *, allow_cpu: bool = False, smoke: bool = False,
     if patch_engine is not None:
         patch_engine(eng)
     below = cfg["token_ids_below"]
+    probe0 = host.probe_ms()
     for r in kind.warmup(tp, args.seed, below):
         eng.submit(np.asarray(r["prompt"], np.int32),
                    max_new_tokens=r["max_new_tokens"], stream=r["stream"],
@@ -274,10 +326,12 @@ def run(args, *, allow_cpu: bool = False, smoke: bool = False,
     window_start = time.time()
     setup_s = window_start - process_start()
     print(f"setup: {setup_s} s, compile {clock.total} s")
-    raw = drive.run_window(eng, kind, window, args.seconds,
-                           ragged=cfg["engine"]["ragged_kernel"],
-                           tracer=tracer)
+    with host.GcLog() as gc_log:
+        raw = drive.run_window(eng, kind, window, args.seconds,
+                               ragged=cfg["engine"]["ragged_kernel"],
+                               tracer=tracer)
     compiles = clock.events - compiles0
+    probe1 = host.probe_ms()
     stats = dev.memory_stats() or {}
     mem_peak = stats.get("peak_bytes_in_use")
     trace = None
@@ -293,6 +347,13 @@ def run(args, *, allow_cpu: bool = False, smoke: bool = False,
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
     rec = record(cell, raw, trace)
     rec["peak"] = peak
+    host_ms = load_module(os.path.join(HERE, "metrics",
+                                       "engine.host_ms_p50.py"),
+                          "host_ms").compute(dict(rec, notes={}))
+    host_line = (f"host: probe ms before warm-up {probe0}, after "
+                 f"the window {probe1}; collections in the window "
+                 f"{gc_log.summary(raw['t0'], raw['end'])}; "
+                 f"engine.iteration - engine.fetch ms p50 {host_ms}")
     finished = [{"prompt": np.asarray(t.req.prompt),
                  "generated": list(t.req.generated)}
                 for t in raw["tracked"] if t.done]
@@ -315,6 +376,7 @@ def run(args, *, allow_cpu: bool = False, smoke: bool = False,
     print(f"submission lateness ms: p50 {_pct(late, 50)}, "
           f"p95 {_pct(late, 95)}, max {max(late, default=None)}")
     print(f"compile events inside the window: {compiles}")
+    print(host_line)
     print(f"counters over the window: {rec['counters']}")
     if args.trace:
         metrics = {}
